@@ -13,7 +13,7 @@ one place — not editing checker code:
     decode/prefill steps and the trainer's fused segment scan.
   * ``BANNED_PRIMITIVES`` — primitives that must never appear inside a
     jitted protocol-plane program (host callbacks stall the device pipeline;
-    ``debug_callback`` is what ``jax.debug.print`` lowers to).
+    ``debug_print`` is what ``jax.debug.print`` lowers to).
   * ``KERNEL_CONTRACTS`` — per kernel family: upper bounds for tile dims the
     linter cannot resolve statically (the TPU-target shapes), and a VMEM
     footprint budget for the sum of all declared BlockSpec tiles
@@ -113,7 +113,8 @@ BANNED_PRIMITIVES = frozenset({
     "pure_callback",        # host round-trip inside the hot path
     "io_callback",
     "callback",
-    "debug_callback",       # jax.debug.print / jax.debug.callback
+    "debug_callback",       # jax.debug.callback
+    "debug_print",          # jax.debug.print
     "infeed", "outfeed",    # legacy host transfers
 })
 

@@ -125,6 +125,21 @@ def _jit_gen_frames():
     return jax.jit(gen, static_argnums=(2, 3, 4))
 
 
+def make_single_step(mcfg: ModelConfig, *, weight_decay: float):
+    """One worker's inner step: loss + grad of `api.loss_fn`, then AdamW.
+    `(params, opt_state, batch, lr) -> (params, opt_state, loss)`; the
+    trainer vmaps it over the worker axis (and `SegmentRunner` scans it)."""
+
+    def single_step(params, opt_state, batch, lr):
+        (loss, metrics), grads = jax.value_and_grad(
+            lambda p: api.loss_fn(mcfg, p, batch), has_aux=True)(params)
+        params, opt_state = adamw_update(grads, opt_state, params, lr,
+                                         weight_decay=weight_decay)
+        return params, opt_state, loss
+
+    return single_step
+
+
 class SegmentRunner:
     """Fused inner-step executor: scans `single_step` (vmapped over the worker
     axis) across a stacked batch segment, carrying (params_stack, opt_state)
@@ -246,15 +261,8 @@ class CrossRegionTrainer:
         # this root, never constructed from raw step arithmetic
         self._frame_key = jax.random.PRNGKey(0x5EED)
 
-        mcfg, tc = model_cfg, tcfg
-
-        def single_step(params, opt_state, batch, lr):
-            (loss, metrics), grads = jax.value_and_grad(
-                lambda p: api.loss_fn(mcfg, p, batch), has_aux=True)(params)
-            params, opt_state = adamw_update(grads, opt_state, params, lr,
-                                             weight_decay=tc.weight_decay)
-            return params, opt_state, loss
-
+        mcfg = model_cfg
+        single_step = make_single_step(mcfg, weight_decay=tcfg.weight_decay)
         self._train_step = jax.jit(jax.vmap(single_step,
                                             in_axes=(0, 0, 0, None)))
         self.segment_runner = SegmentRunner(single_step,

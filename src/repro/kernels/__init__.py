@@ -17,16 +17,7 @@ Kernels:
   outer_update    — fused outer Nesterov step + fused delivery (Eq. 3 blend /
                     Algorithm-1 compensation + offline masking) over the flat
                     fragment plane — one dispatch per protocol transition
-
-`tpu_compiler_params` papers over the Pallas API rename: the TPU compiler-params
-class is `pltpu.TPUCompilerParams` up to jax 0.4.x and `pltpu.CompilerParams`
-from jax 0.5+. Kernels import the alias instead of naming either directly.
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# version-compatible alias (TPUCompilerParams was renamed to CompilerParams)
-tpu_compiler_params = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams")
 
 
 def is_cpu() -> bool:

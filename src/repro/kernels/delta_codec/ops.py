@@ -19,8 +19,8 @@ Implementation policy (`impl`):
              wire bytes exactly
   "auto"   — oracle on CPU (interpret mode is python-per-tile and the codec
              sits on the engine's per-initiation hot path), kernel elsewhere;
-             also falls back to the oracle when the kernel's block-alignment
-             requirement is unmet
+             off the CPU an unaligned block raises, as for "pallas", so the
+             device path never silently runs the oracle
 
 `wire_bytes` is the ONE place the compressed payload size is computed —
 `ProtocolEngine._wire_bytes` calls it so transfer times, link pricing and the
@@ -50,16 +50,14 @@ def wire_bytes(n_elems: int, *, codec: str, block: int) -> int:
 
 
 def _use_ref(impl: str, block: int) -> bool:
-    if impl == "ref":
+    if impl == "ref" or (impl == "auto" and is_cpu()):
         return True
-    aligned = block % KERNEL_BLOCK_MULTIPLE == 0
-    if impl == "pallas":
-        if not aligned:
-            raise ValueError(
-                f"impl='pallas' requires block % {KERNEL_BLOCK_MULTIPLE} == 0 "
-                f"(int4 halves-packing lane alignment), got block={block}")
-        return False
-    return is_cpu() or not aligned
+    if block % KERNEL_BLOCK_MULTIPLE:
+        raise ValueError(
+            f"impl={impl!r} runs the kernel, which requires block % "
+            f"{KERNEL_BLOCK_MULTIPLE} == 0 (int4 halves-packing lane "
+            f"alignment), got block={block}")
+    return False
 
 
 def _blocked(x, block: int):

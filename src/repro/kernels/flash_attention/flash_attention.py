@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 NEG_INF = -1e30
@@ -31,9 +29,8 @@ NEG_INF = -1e30
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             bq, bk, sk, causal, window, scale):
-    # refs arrive squeezed to (S, hd) via None block dims, so every access is
-    # a single NDIndexer (interpret-mode discharge supports exactly one
-    # indexer per load/store in this jax version)
+    # refs arrive squeezed to (S, hd) via None block dims, so each KV block
+    # is one plain ref slice
     qi = pl.program_id(2)
     q = q_ref[...].astype(jnp.float32) * scale                 # (BQ, hd)
 
@@ -54,8 +51,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     def body(ki, _):
         k_start = ki * bk
-        k = pl.load(k_ref, (pl.ds(k_start, bk), slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(k_start, bk), slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(k_start, bk), :].astype(jnp.float32)
+        v = v_ref[pl.ds(k_start, bk), :].astype(jnp.float32)
         s = q @ k.T                                            # (BQ, BK)
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -119,7 +116,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
         name="flash_attention_gqa",

@@ -18,10 +18,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 BLOCK_C = 512
 NEG_INF = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref, m_scr, l_scr,
@@ -37,22 +36,25 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref, m_scr, l_scr,
     q = q_ref[...].astype(jnp.float32) * scale          # (G, hd)
     k = k_ref[...].astype(jnp.float32)                  # (BC, hd)
     v = v_ref[...].astype(jnp.float32)
-    kv_pos = pos_ref[...][0]                            # (BC,) int32
+    kv_pos = pos_ref[...]                               # (1, BC) int32
     qpos = qpos_ref[pl.program_id(0)]
 
-    s = q @ k.T                                         # (G, BC)
+    # f32 matmuls (the TPU default would round operands to bf16), matching
+    # the oracle
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=HIGHEST)          # (G, BC)
     valid = (kv_pos >= 0) & (kv_pos <= qpos)
     if window is not None:
         valid &= (qpos - kv_pos) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_cur = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.where(valid[None, :], jnp.exp(s - m_new), 0.0)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + p @ v
+    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(p, v, precision=HIGHEST)
     m_scr[...] = m_new
 
     @pl.when(ci == n_c - 1)
@@ -79,11 +81,14 @@ def flash_decode_bkv(q, k_cache, v_cache, kv_positions, q_position, *,
 
     q_spec = pl.BlockSpec((1, 1, G, hd), lambda b, h, c: (b, h, 0, 0))
     kv_spec = pl.BlockSpec((1, 1, bc, hd), lambda b, h, c: (b, h, c, 0))
-    pos_spec = pl.BlockSpec((1, bc), lambda b, h, c: (b, c))
+    # positions ride as (B, 1, C) so the block's last two dims are (1, bc):
+    # a (1, bc) block of a (B, C) array breaks the TPU's 8-sublane tiling
+    # whenever B > 1
+    pos_spec = pl.BlockSpec((1, 1, bc), lambda b, h, c: (b, 0, c))
 
     def squeeze(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref, m, l, acc):
         _kernel(qpos_ref, q_ref.at[0, 0], k_ref.at[0, 0], v_ref.at[0, 0],
-                pos_ref, o_ref.at[0, 0], m, l, acc,
+                pos_ref.at[0], o_ref.at[0, 0], m, l, acc,
                 bc=bc, n_c=n_c, window=window, scale=scale)
 
     return pl.pallas_call(
@@ -98,8 +103,9 @@ def flash_decode_bkv(q, k_cache, v_cache, kv_positions, q_position, *,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_decode_gqa",
-    )(jnp.asarray(q_position, jnp.int32), q, k_cache, v_cache, kv_positions)
+    )(jnp.asarray(q_position, jnp.int32), q, k_cache, v_cache,
+      kv_positions[:, None, :])

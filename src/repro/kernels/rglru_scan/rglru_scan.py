@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 BLOCK_T = 256
 BLOCK_D = 128
 
@@ -65,7 +63,7 @@ def lru_scan_btd(a, b, h0, *, bt=BLOCK_T, bd=BLOCK_D, interpret=False):
         out_specs=data_spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rglru_scan",

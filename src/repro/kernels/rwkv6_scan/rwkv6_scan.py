@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 BLOCK_T = 128
 
 
@@ -75,7 +73,7 @@ def wkv_scan_bht(r, k, v, w, u, s0, *, bt=BLOCK_T, interpret=False):
         out_shape=[jax.ShapeDtypeStruct(r.shape, r.dtype),
                    jax.ShapeDtypeStruct((BH, hd, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="rwkv6_wkv_scan",

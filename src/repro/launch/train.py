@@ -25,6 +25,7 @@ import time
 
 from repro.api import ExperimentSpec, build_experiment, registered_methods
 from repro.core.network import MESH_PROFILES, SCENARIOS
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def spec_from_args(args) -> ExperimentSpec:
@@ -209,6 +210,7 @@ def main(argv=None):
     if spec.run.ckpt_every and not args.ckpt:
         ap.error("--ckpt-every requires --ckpt (nowhere to save)")
 
+    enable_compile_cache()
     trainer = build_experiment(spec)
     if args.resume:
         from repro.checkpoint import load_pytree

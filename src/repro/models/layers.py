@@ -85,12 +85,15 @@ def _split_heads(x, n, hd):
 
 
 def gqa_attention(q, k, v, *, causal: bool, window: Optional[int],
-                  q_positions=None, kv_positions=None, kv_mask=None):
+                  q_positions=None, kv_positions=None, kv_mask=None,
+                  precision=None):
     """Reference GQA attention.
 
     q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd). H % KV == 0.
     window: sliding window size (attend to keys with q_pos - k_pos < window).
     kv_mask: (B, Sk) bool validity mask (decode caches / padded encoders).
+    precision: of both einsums (None = the backend default, which on TPU
+    rounds the f32 softmax probabilities to bf16 for the PV product).
     Returns (B, Sq, H, hd).
     """
     B, Sq, H, hd = q.shape
@@ -99,7 +102,8 @@ def gqa_attention(q, k, v, *, causal: bool, window: Optional[int],
     qh = q.reshape(B, Sq, KV, group, hd)
     scale = 1.0 / math.sqrt(hd)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", qh.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale            # (B,KV,g,Sq,Sk)
+                        k.astype(jnp.float32),
+                        precision=precision) * scale              # (B,KV,g,Sq,Sk)
     if q_positions is None:
         q_positions = jnp.arange(Sq)[None, :]
     if kv_positions is None:
@@ -115,7 +119,8 @@ def gqa_attention(q, k, v, *, causal: bool, window: Optional[int],
         mask &= kv_mask[:, None, None, None, :]
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32),
+                     precision=precision)
     return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 

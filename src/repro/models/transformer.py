@@ -187,6 +187,15 @@ def init_cache(cfg: ModelConfig, batch_size: int, cache_len: int):
     }
 
 
+def _decode_attention(q, kc, vc, kv_pos, pos, window, attn_impl):
+    """One decode token's attention over the ring cache: the flash_decode
+    kernel ("flash") or its oracle ("ref"). q: (B, 1, H, hd)."""
+    from repro.kernels.flash_decode import ops as fd_ops
+    impl = {"flash": "auto", "ref": "ref"}[attn_impl]
+    return fd_ops.flash_decode(q[:, 0], kc, vc, kv_pos, pos, window=window,
+                               impl=impl)[:, None]
+
+
 def decode_step(cfg: ModelConfig, params, cache, tokens, *,
                 window: Optional[int] = None, attn_impl: str = "ref",
                 unroll: bool = False):
@@ -201,8 +210,6 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
     x = params["embed"][tokens][:, None, :].astype(jnp.dtype(cfg.compute_dtype))
     positions = jnp.broadcast_to(pos[None, None], (B, 1)).astype(jnp.int32)
     kv_pos = cache["kv_pos"].at[slot].set(pos)
-    kv_positions = jnp.broadcast_to(kv_pos[None], (B, C))
-    kv_mask = kv_positions >= 0
 
     def body(x, xs):
         lp, kc, vc = xs
@@ -210,14 +217,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
         q, k, v = attn_qkv(h, lp["attn"], cfg, positions)
         kc = jax.lax.dynamic_update_slice(kc, k, (0, slot, 0, 0))
         vc = jax.lax.dynamic_update_slice(vc, v, (0, slot, 0, 0))
-        if attn_impl == "flash":
-            from repro.kernels.flash_decode import ops as fd_ops
-            o = fd_ops.flash_decode(q[:, 0], kc, vc, kv_pos, pos,
-                                    window=window)[:, None]
-        else:
-            o = gqa_attention(q, kc, vc, causal=True, window=window,
-                              q_positions=positions, kv_positions=kv_positions,
-                              kv_mask=kv_mask)
+        o = _decode_attention(q, kc, vc, kv_pos, pos, window, attn_impl)
         x = x + attn_out(o, lp["attn"], cfg)
         h = rms_norm(x, lp["ln2"], cfg.rms_eps)
         if cfg.moe is not None:
@@ -278,7 +278,6 @@ def decode_step_slotted(cfg: ModelConfig, params, cache, tokens, *, active,
     x = params["embed"][tokens][:, None, :].astype(jnp.dtype(cfg.compute_dtype))
     positions = pos[:, None]                            # (B, 1)
     kv_pos = cache["kv_pos"].at[bidx, slot].set(pos, mode="drop")
-    kv_mask = kv_pos >= 0
 
     def body(x, xs):
         lp, kc, vc = xs
@@ -286,14 +285,7 @@ def decode_step_slotted(cfg: ModelConfig, params, cache, tokens, *, active,
         q, k, v = attn_qkv(h, lp["attn"], cfg, positions)
         kc = kc.at[bidx, slot].set(k[:, 0], mode="drop")
         vc = vc.at[bidx, slot].set(v[:, 0], mode="drop")
-        if attn_impl == "flash":
-            from repro.kernels.flash_decode import ops as fd_ops
-            o = fd_ops.flash_decode(q[:, 0], kc, vc, kv_pos, pos,
-                                    window=window)[:, None]
-        else:
-            o = gqa_attention(q, kc, vc, causal=True, window=window,
-                              q_positions=positions, kv_positions=kv_pos,
-                              kv_mask=kv_mask)
+        o = _decode_attention(q, kc, vc, kv_pos, pos, window, attn_impl)
         x = x + attn_out(o, lp["attn"], cfg)
         h = rms_norm(x, lp["ln2"], cfg.rms_eps)
         if cfg.moe is not None:

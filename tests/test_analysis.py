@@ -57,7 +57,7 @@ def test_banned_primitive_trips_on_debug_print():
         jax.debug.print("x={x}", x=x)
         return x + 1.0
     jaxpr = jax.make_jaxpr(f)(jnp.ones((3,))).jaxpr
-    with pytest.raises(AuditError, match="debug_callback"):
+    with pytest.raises(AuditError, match="debug_print"):
         check_banned_primitives(jaxpr, "fixture")
     # a clean program passes
     check_banned_primitives(
@@ -65,7 +65,7 @@ def test_banned_primitive_trips_on_debug_print():
 
 
 def test_f64_check_trips_under_x64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(jnp.ones((3,))).jaxpr
         with pytest.raises(AuditError, match="float64"):

@@ -61,12 +61,17 @@ def test_ref_pallas_bitwise_parity(codec, block, shape):
     np.testing.assert_array_equal(np.asarray(rt_r), np.asarray(rt_k))
 
 
-def test_pallas_rejects_unaligned_block():
+def test_pallas_rejects_unaligned_block(monkeypatch):
     x = rand((128,))
     with pytest.raises(ValueError, match="block"):
         codec_ops.encode_array(x, codec="int8", block=10, impl="pallas")
-    # auto silently falls back to the oracle for the same block
+    # on the CPU auto is the oracle, which takes any block
     codec_ops.codec_roundtrip_array(x, codec="int8", block=10, impl="auto")
+    # off the CPU auto means the kernel: an unaligned block raises instead
+    # of quietly running the oracle on the device
+    monkeypatch.setattr(codec_ops, "is_cpu", lambda: False)
+    with pytest.raises(ValueError, match="block"):
+        codec_ops.encode_array(x, codec="int8", block=10, impl="auto")
 
 
 def test_int4_pack_unpack_exact():
