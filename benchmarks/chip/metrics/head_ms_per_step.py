@@ -1,0 +1,11 @@
+"""Device milliseconds per inner step of the segment program's ops under the
+named scope `head`: the head (final norm, LM head and cross-entropy),
+forward, remat and backward (`program_trace.reduce_program`'s `scopes`)."""
+
+
+def read(run):
+    scope = run["trace"].get("scopes", {}).get("head")
+    steps = run["record"].get("steps")
+    if not scope or not steps:
+        return None
+    return 1e3 * scope["s"] / steps

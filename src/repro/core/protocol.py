@@ -69,6 +69,7 @@ from repro.core.fragments import Fragmenter
 from repro.core.methods import get_method
 from repro.core.network import (CommPlan, FairShareSim, RoutePlanner, Topology,
                                 as_topology)
+from repro.telemetry import Counters, span
 
 # Host-scheduler checkpoint schema. One upgrade path
 # (`upgrade_scheduler_state`) replaces the `.get(...)`-default sprawl that
@@ -168,11 +169,16 @@ class PendingSync:
 
 class ProtocolEngine:
     """One engine instance per training run. Device state is functional
-    (`self.state`); host methods schedule transitions and account wall-clock."""
+    (`self.state`); host methods schedule transitions and account wall-clock.
+    The hook and each transition are host spans (`repro.protocol`,
+    `.deliver`, `.select`, `.initiate`); initiations, deliveries and blocking
+    reads are counted in `telemetry`, the trainer's `Counters` when it built
+    the engine."""
 
     def __init__(self, method: str, ccfg: CoCoDCConfig, fragmenter: Fragmenter,
                  network, params_stack, *, dc_impl: str = "ref",
-                 engine_impl: str = "jit"):
+                 engine_impl: str = "jit",
+                 telemetry: "Counters | None" = None):
         # registry lookup — unknown names raise listing registered methods
         self.method_impl = get_method(method)
         assert engine_impl in ("jit", "host")
@@ -187,6 +193,7 @@ class ProtocolEngine:
         self.K = ccfg.num_fragments
         self.H = ccfg.local_steps
         self.tau = ccfg.overlap_depth
+        self.telemetry = Counters() if telemetry is None else telemetry
 
         # fused_updates stores theta_g/momentum as flat planes; keep the
         # single-model leaf shapes so the pytree views can materialize at the
@@ -341,9 +348,11 @@ class ProtocolEngine:
 
     @property
     def adaptive(self) -> adaptive_lib.AdaptiveState:
-        """Host snapshot of the Eq. 11 scheduler state (reads device arrays)."""
-        rate = np.asarray(self.state.rate)
-        last = np.asarray(self.state.last_sync)
+        """Host snapshot of the Eq. 11 scheduler state. This BLOCKS until the
+        device has computed the rates and last-sync steps (host sync
+        `adaptive_rate`)."""
+        rate, last = self.telemetry.read(
+            "adaptive_rate", (self.state.rate, self.state.last_sync))
         return adaptive_lib.AdaptiveState(
             K=self.K, H=self.H,
             last_sync=[int(x) for x in last],
@@ -631,21 +640,26 @@ class ProtocolEngine:
     # ------------------------------------------------------------ initiation
 
     def _initiate(self, t: int, params_stack, p: int):
-        nbytes = self.frag.fragment_bytes(p)
-        tid = self.n_syncs              # _schedule_transfer's id, pre-bump
-        finish, duration = self._schedule_transfer(nbytes)
-        self.state = self._fns.initiate(self.state, t, params_stack, p)
-        self.pending.append(PendingSync(
-            frag=p, t_init=t, deliver_at=self._deliver_step_for(t, finish),
-            finish_time=finish, seq=self._seq, duration=duration,
-            wire=self._wire_bytes(nbytes), tid=tid))
-        self._seq += 1
+        with span("protocol.initiate", frag=p):
+            nbytes = self.frag.fragment_bytes(p)
+            tid = self.n_syncs          # _schedule_transfer's id, pre-bump
+            finish, duration = self._schedule_transfer(nbytes)
+            self.state = self._fns.initiate(self.state, t, params_stack, p)
+            self.pending.append(PendingSync(
+                frag=p, t_init=t, deliver_at=self._deliver_step_for(t, finish),
+                finish_time=finish, seq=self._seq, duration=duration,
+                wire=self._wire_bytes(nbytes), tid=tid))
+            self._seq += 1
+            self.telemetry.initiations += 1
 
     def _select_cocodc(self, t: int, busy: set) -> int:
+        """Algorithm 2's fragment; blocks on `adaptive`'s device read."""
         # _frag_cost tracks the wall-clock plan (refreshed in on_step_end
         # before deliveries/initiations), so pricing sees the CURRENT routes
-        costs = self._frag_cost if self.cfg.link_pricing else None
-        return adaptive_lib.select_fragment(self.adaptive, t, busy, costs=costs)
+        with span("protocol.select"):
+            costs = self._frag_cost if self.cfg.link_pricing else None
+            return adaptive_lib.select_fragment(self.adaptive, t, busy,
+                                                costs=costs)
 
     # ------------------------------------------------------ event-driven API
 
@@ -675,14 +689,15 @@ class ProtocolEngine:
         dispatches the method strategy's protocol action (blocking round,
         delivery processing + initiation, or nothing). Returns the updated
         params_stack."""
-        self.wall_clock += self.topology.t_c
-        out = self.method_impl.on_step_end(self, t, params_stack)
-        if self._fairshare is not None:
-            # advance the fluid sim to the post-step wall-clock, finalizing
-            # flows that finished (advance is associative, so per-step and
-            # segment-fused loops land on identical sim states)
-            self._fairshare.advance(self.wall_clock)
-        return out
+        with span("protocol", t=t):
+            self.wall_clock += self.topology.t_c
+            out = self.method_impl.on_step_end(self, t, params_stack)
+            if self._fairshare is not None:
+                # advance the fluid sim to the post-step wall-clock,
+                # finalizing flows that finished (advance is associative, so
+                # per-step and segment-fused loops land on identical states)
+                self._fairshare.advance(self.wall_clock)
+            return out
 
     def _process_deliveries(self, t: int, params_stack):
         """Apply every in-flight delivery due at step t (delivery order:
@@ -691,13 +706,15 @@ class ProtocolEngine:
         due = sorted((ev for ev in self.pending if ev.deliver_at <= t),
                      key=lambda e: (e.deliver_at, e.seq))
         for ev in due:
-            self.state, params_stack = self._fns.deliver(
-                self.state, t, params_stack, ev.frag)
-            self.pending.remove(ev)
-            if self._resync is not None:
-                # a COMPLETED transfer's measured duration is shared history
-                # (paired with its wire bytes for the Eq. 9 byte fit)
-                self._resync.observe(ev.duration, ev.wire)
+            with span("protocol.deliver", frag=ev.frag, t_init=ev.t_init):
+                self.state, params_stack = self._fns.deliver(
+                    self.state, t, params_stack, ev.frag)
+                self.pending.remove(ev)
+                if self._resync is not None:
+                    # a COMPLETED transfer's measured duration is shared
+                    # history (paired with its wire bytes for the Eq. 9 fit)
+                    self._resync.observe(ev.duration, ev.wire)
+                self.telemetry.deliveries += 1
         return params_stack
 
     # ---------------------------------------------------------- checkpointing

@@ -43,6 +43,7 @@ from repro.data.pipeline import (MarkovCorpus, make_worker_streams,
 from repro.models import api
 from repro.optim import adamw_init, adamw_update, warmup_cosine
 from repro.optim.adamw import AdamWState
+from repro.telemetry import Counters, span
 
 
 @dataclasses.dataclass
@@ -160,6 +161,10 @@ class SegmentRunner:
     is gated on the backend (`donate` overrides the gate — used by the
     static-analysis donation audit to inspect the accelerator wiring).
 
+    Each call is the host span `repro.dispatch` (chunk slicing, the scan
+    dispatches, the loss concat); every chunk counts one scan dispatch in
+    `telemetry`, the trainer's `Counters` when it built the runner.
+
     The power-of-two contract is ENFORCED, not just relied on: the jitted
     scan is wrapped in a `RetraceSentinel` with budget log2(max_segment)+1
     (one compiled program per chunk length 1, 2, ..., max_segment), so an
@@ -169,9 +174,11 @@ class SegmentRunner:
     DONATE_ARGNUMS = (0, 1)              # params_stack, opt_state (scan carry)
 
     def __init__(self, single_step, *, max_segment: int = 64,
-                 donate: bool | None = None):
+                 donate: bool | None = None,
+                 telemetry: Optional[Counters] = None):
         self.single_step = single_step
         self.max_segment = int(max_segment)
+        self.telemetry = Counters() if telemetry is None else telemetry
         vstep = jax.vmap(single_step, in_axes=(0, 0, 0, None))
 
         def run_segment(params_stack, opt_state, batch_seg, lrs):
@@ -198,17 +205,19 @@ class SegmentRunner:
 
     def __call__(self, params_stack, opt_state, batch_seg, lrs):
         n = int(lrs.shape[0])
-        loss_chunks = []
-        i = 0
-        while i < n:
-            c = 1 << ((n - i).bit_length() - 1)   # largest power of two <= n-i
-            chunk = jax.tree.map(lambda x: x[i:i + c], batch_seg)
-            params_stack, opt_state, losses = self._fn(
-                params_stack, opt_state, chunk, lrs[i:i + c])
-            loss_chunks.append(losses)
-            i += c
-        losses = (loss_chunks[0] if len(loss_chunks) == 1
-                  else jnp.concatenate(loss_chunks))
+        with span("dispatch", chunks=bin(n).count("1")):
+            loss_chunks = []
+            i = 0
+            while i < n:
+                c = 1 << ((n - i).bit_length() - 1)   # largest 2^k <= n - i
+                chunk = jax.tree.map(lambda x: x[i:i + c], batch_seg)
+                params_stack, opt_state, losses = self._fn(
+                    params_stack, opt_state, chunk, lrs[i:i + c])
+                self.telemetry.scan_dispatched()
+                loss_chunks.append(losses)
+                i += c
+            losses = (loss_chunks[0] if len(loss_chunks) == 1
+                      else jnp.concatenate(loss_chunks))
         return params_stack, opt_state, losses
 
 
@@ -227,6 +236,8 @@ class CrossRegionTrainer:
         # primary resume-identity check.
         self.spec = spec
         M = ccfg.num_workers
+        # host spans' counters (repro.telemetry): never saved, never in history
+        self.telemetry = Counters()
 
         key = jax.random.PRNGKey(tcfg.seed)
         params = api.init_params(model_cfg, key)
@@ -250,7 +261,8 @@ class CrossRegionTrainer:
         self.network = network
         self.engine = ProtocolEngine(tcfg.method, ccfg, self.fragmenter, network,
                                      self.params_stack,
-                                     engine_impl=tcfg.engine_impl)
+                                     engine_impl=tcfg.engine_impl,
+                                     telemetry=self.telemetry)
 
         self.streams = make_worker_streams(M, model_cfg.vocab, seed=tcfg.seed,
                                            noniid_frac=tcfg.noniid_frac)
@@ -266,7 +278,8 @@ class CrossRegionTrainer:
         self._train_step = jax.jit(jax.vmap(single_step,
                                             in_axes=(0, 0, 0, None)))
         self.segment_runner = SegmentRunner(single_step,
-                                            max_segment=tcfg.max_segment)
+                                            max_segment=tcfg.max_segment,
+                                            telemetry=self.telemetry)
 
         def eval_loss(params, batch):
             loss, metrics = api.loss_fn(mcfg, params, batch)
@@ -330,38 +343,48 @@ class CrossRegionTrainer:
             self.params_stack, self.opt_state, batch, self.lr(t))
         self.params_stack = self.engine.on_step_end(t, self.params_stack)
         self.step += 1
-        return float(jnp.mean(losses))
+        self.telemetry.steps += 1
+        return float(self.telemetry.read("loss", jnp.mean(losses)))
 
     def _run_segment(self, t0: int, n: int) -> float:
         """Run steps [t0, t0+n) as one scanned dispatch. The segment is chosen
         so only its LAST step can be a protocol event; quiet steps advance the
-        simulated wall-clock without touching the engine."""
-        batch_seg = stacked_segment(self.streams, t0, n, self.tcfg.local_batch,
-                                    self.tcfg.seq_len)
-        batch_seg = self._augment_segment(batch_seg, t0, n)
-        lrs = self.lr(jnp.arange(t0, t0 + n))
-        self.params_stack, self.opt_state, losses = self.segment_runner(
-            self.params_stack, self.opt_state, batch_seg, lrs)
-        if n > 1:
-            self.engine.advance_steps(n - 1)
-        self.params_stack = self.engine.on_step_end(t0 + n - 1,
-                                                    self.params_stack)
-        self.step = t0 + n
-        return float(jnp.mean(losses[-1]))
+        simulated wall-clock without touching the engine. Returns the mean
+        loss of the last step, which BLOCKS on the whole segment (host sync
+        `loss`)."""
+        with span("segment", t0=t0, n=n):
+            with span("data", t0=t0, n=n):
+                batch_seg = stacked_segment(self.streams, t0, n,
+                                            self.tcfg.local_batch,
+                                            self.tcfg.seq_len)
+                batch_seg = self._augment_segment(batch_seg, t0, n)
+            with span("lr"):
+                lrs = self.lr(jnp.arange(t0, t0 + n))
+            self.params_stack, self.opt_state, losses = self.segment_runner(
+                self.params_stack, self.opt_state, batch_seg, lrs)
+            if n > 1:
+                self.engine.advance_steps(n - 1)
+            self.params_stack = self.engine.on_step_end(t0 + n - 1,
+                                                        self.params_stack)
+            self.step = t0 + n
+            self.telemetry.segments += 1
+            self.telemetry.steps += n
+            return float(self.telemetry.read("loss", jnp.mean(losses[-1])))
 
     def _segment_end(self, t: int, target: int, eval_every: int,
                      ckpt_every: int) -> int:
         """Last step (inclusive) of the segment starting at t: the earliest of
         the next protocol event, the next eval/checkpoint boundary, and the end
-        of the run."""
-        end = min(target - 1, t + self.tcfg.max_segment - 1)
-        ne = self.engine.next_event_step(t)
-        if ne is not None:
-            end = min(end, ne)
-        for every in (eval_every, ckpt_every):
-            if every:
-                end = min(end, (t // every + 1) * every - 1)
-        return end
+        of the run (host span `repro.plan`)."""
+        with span("plan", t0=t):
+            end = min(target - 1, t + self.tcfg.max_segment - 1)
+            ne = self.engine.next_event_step(t)
+            if ne is not None:
+                end = min(end, ne)
+            for every in (eval_every, ckpt_every):
+                if every:
+                    end = min(end, (t // every + 1) * every - 1)
+            return end
 
     # ------------------------------------------------------------------ eval
 
@@ -373,9 +396,10 @@ class CrossRegionTrainer:
             batch = self.eval_stream.batch(10_000_000 + i, self.tcfg.eval_batch,
                                            self.tcfg.seq_len)
             batch = self._augment(batch, 10_000_000 + i, stacked=False)
-            nll += float(self._eval(theta, batch))
+            nll += float(self.telemetry.read("eval", self._eval(theta, batch)))
         nll /= n_batches
-        return {"nll": nll, "ppl": float(jnp.exp(nll))}
+        return {"nll": nll,
+                "ppl": float(self.telemetry.read("eval", jnp.exp(nll)))}
 
     # ------------------------------------------------------------------- run
 

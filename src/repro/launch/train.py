@@ -246,6 +246,7 @@ def main(argv=None):
     link_stats = trainer.engine.link_stats()
     print(f"done in {dt:.1f}s host-time; simulated wall {stats['wall_clock_s']:.0f}s;"
           f" comm hidden {stats['overlap_ratio']*100:.0f}%", flush=True)
+    print(f"host counters: {trainer.telemetry.line()}", flush=True)
     if stats.get("stall_seconds"):
         print(f"dynamic links: stalled {stats['stall_seconds']:.1f}s "
               f"({stats['stall_fraction']*100:.0f}% of WAN time), "

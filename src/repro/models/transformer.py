@@ -95,38 +95,47 @@ def _seq_shard(x):
 
 def _layer(cfg: ModelConfig, x, lp, positions, window, attn_impl,
            seq_parallel=False):
+    # named scopes `attention` and `mlp` reach every op's HLO metadata
+    # (forward, remat recompute and backward), so a device trace can be
+    # split by layer
     if seq_parallel:
         x = _seq_shard(x)
-    h = rms_norm(x, lp["ln1"], cfg.rms_eps)
-    q, k, v = attn_qkv(h, lp["attn"], cfg, positions)
-    if attn_impl == "flash":
-        from repro.kernels.flash_attention import ops as flash_ops
-        o = flash_ops.flash_attention(q, k, v, causal=True, window=window)
-    else:
-        o = gqa_attention(q, k, v, causal=True, window=window,
-                          q_positions=positions, kv_positions=positions)
-    x = x + attn_out(o, lp["attn"], cfg)
-    h = rms_norm(x, lp["ln2"], cfg.rms_eps)
-    if cfg.moe is not None:
-        out = moe_lib.moe_ffn(h, lp["moe"], cfg.moe)
-        return x + out.y, out.aux_loss
-    return x + swiglu(h, lp["mlp"]["w_gate"], lp["mlp"]["w_up"], lp["mlp"]["w_down"]), \
-        jnp.zeros((), jnp.float32)
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["ln1"], cfg.rms_eps)
+        q, k, v = attn_qkv(h, lp["attn"], cfg, positions)
+        if attn_impl == "flash":
+            from repro.kernels.flash_attention import ops as flash_ops
+            o = flash_ops.flash_attention(q, k, v, causal=True, window=window)
+        else:
+            o = gqa_attention(q, k, v, causal=True, window=window,
+                              q_positions=positions, kv_positions=positions)
+        x = x + attn_out(o, lp["attn"], cfg)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["ln2"], cfg.rms_eps)
+        if cfg.moe is not None:
+            out = moe_lib.moe_ffn(h, lp["moe"], cfg.moe)
+            return x + out.y, out.aux_loss
+        return x + swiglu(h, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
+                          lp["mlp"]["w_down"]), jnp.zeros((), jnp.float32)
 
 
 def embed_inputs(cfg: ModelConfig, params, batch):
-    """Token (+ vlm prefix) embedding. Returns (x, positions, n_prefix)."""
-    emb = params["embed"]
-    x = emb[batch["tokens"]].astype(jnp.dtype(cfg.compute_dtype))
-    n_prefix = 0
-    if cfg.family == "vlm" and "prefix_emb" in batch:
-        pj = params["projector"]
-        pe = jax.nn.gelu(batch["prefix_emb"].astype(pj["w1"].dtype) @ pj["w1"]) @ pj["w2"]
-        x = jnp.concatenate([pe.astype(x.dtype), x], axis=1)
-        n_prefix = pe.shape[1]
-    B, S = x.shape[:2]
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    return x, positions, n_prefix
+    """Token (+ vlm prefix) embedding, named scope `embed`. Returns
+    (x, positions, n_prefix)."""
+    with jax.named_scope("embed"):
+        emb = params["embed"]
+        x = emb[batch["tokens"]].astype(jnp.dtype(cfg.compute_dtype))
+        n_prefix = 0
+        if cfg.family == "vlm" and "prefix_emb" in batch:
+            pj = params["projector"]
+            pe = jax.nn.gelu(batch["prefix_emb"].astype(pj["w1"].dtype)
+                             @ pj["w1"]) @ pj["w2"]
+            x = jnp.concatenate([pe.astype(x.dtype), x], axis=1)
+            n_prefix = pe.shape[1]
+        B, S = x.shape[:2]
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
+                                     (B, S))
+        return x, positions, n_prefix
 
 
 def forward(cfg: ModelConfig, params, batch, *, train: bool = True,
@@ -152,7 +161,8 @@ def forward(cfg: ModelConfig, params, batch, *, train: bool = True,
     else:
         body_fn = jax.checkpoint(body) if (train and remat) else body
         x, auxs = jax.lax.scan(body_fn, x, params["layers"])
-    h = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    with jax.named_scope("head"):
+        h = rms_norm(x, params["final_norm"], cfg.rms_eps)
     return h, {"moe_aux": jnp.sum(auxs), "n_prefix": n_prefix}
 
 
@@ -164,8 +174,9 @@ def loss_fn(cfg: ModelConfig, params, batch, *, attn_impl: str = "ref",
     n_prefix = aux["n_prefix"]
     if n_prefix:
         h = h[:, n_prefix:]
-    nll = chunked_cross_entropy(h, lm_head_weight(cfg, params), batch["labels"],
-                                chunk=xent_chunk)
+    with jax.named_scope("head"):           # with forward's final norm
+        nll = chunked_cross_entropy(h, lm_head_weight(cfg, params),
+                                    batch["labels"], chunk=xent_chunk)
     loss = nll + aux["moe_aux"]
     return loss, {"nll": nll, "moe_aux": aux["moe_aux"], "ppl": jnp.exp(nll)}
 

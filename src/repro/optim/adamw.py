@@ -29,28 +29,31 @@ def global_norm(tree) -> jax.Array:
 
 def adamw_update(grads, state: AdamWState, params, lr, *,
                  b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1, clip_norm=1.0):
-    """Returns (new_params, new_state). lr may be a traced scalar (schedule)."""
-    count = state.count + 1
-    if clip_norm is not None:
-        gn = global_norm(grads)
-        scale = jnp.minimum(1.0, clip_norm / (gn + 1e-9))
-        grads = jax.tree.map(lambda g: g * scale, grads)
-    mu = jax.tree.map(
-        lambda m, g: (b1 * m.astype(jnp.float32)
-                      + (1 - b1) * g.astype(jnp.float32)).astype(m.dtype),
-        state.mu, grads)
-    nu = jax.tree.map(
-        lambda v, g: (b2 * v.astype(jnp.float32)
-                      + (1 - b2) * jnp.square(g.astype(jnp.float32))).astype(v.dtype),
-        state.nu, grads)
-    c1 = 1 - b1 ** count.astype(jnp.float32)
-    c2 = 1 - b2 ** count.astype(jnp.float32)
+    """Returns (new_params, new_state). lr may be a traced scalar (schedule).
+    Runs under the named scope `adamw` (the clip and the update)."""
+    with jax.named_scope("adamw"):
+        count = state.count + 1
+        if clip_norm is not None:
+            gn = global_norm(grads)
+            scale = jnp.minimum(1.0, clip_norm / (gn + 1e-9))
+            grads = jax.tree.map(lambda g: g * scale, grads)
+        mu = jax.tree.map(
+            lambda m, g: (b1 * m.astype(jnp.float32)
+                          + (1 - b1) * g.astype(jnp.float32)).astype(m.dtype),
+            state.mu, grads)
+        nu = jax.tree.map(
+            lambda v, g: (b2 * v.astype(jnp.float32) + (1 - b2)
+                          * jnp.square(g.astype(jnp.float32))).astype(v.dtype),
+            state.nu, grads)
+        c1 = 1 - b1 ** count.astype(jnp.float32)
+        c2 = 1 - b2 ** count.astype(jnp.float32)
 
-    def upd(p, m, v):
-        mhat = m.astype(jnp.float32) / c1
-        vhat = v.astype(jnp.float32) / c2
-        step = mhat / (jnp.sqrt(vhat) + eps) + weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * step).astype(p.dtype)
+        def upd(p, m, v):
+            mhat = m.astype(jnp.float32) / c1
+            vhat = v.astype(jnp.float32) / c2
+            step = (mhat / (jnp.sqrt(vhat) + eps)
+                    + weight_decay * p.astype(jnp.float32))
+            return (p.astype(jnp.float32) - lr * step).astype(p.dtype)
 
-    new_params = jax.tree.map(upd, params, mu, nu)
-    return new_params, AdamWState(mu=mu, nu=nu, count=count)
+        new_params = jax.tree.map(upd, params, mu, nu)
+        return new_params, AdamWState(mu=mu, nu=nu, count=count)
