@@ -6,7 +6,8 @@ Sections (each prints PASS or its violation list; exit 1 if any fail):
   purity             unseeded np.random + wall-clock-in-core lint
   engine-dispatch    pallas_call budgets per method x fused x impl,
                      banned primitives, no-f64 (traced jaxprs)
-  segment-scan       the fused inner-step scan stays pure XLA
+  segment-scan       the fused inner-step scan's pallas_call budget per
+                     attn_impl (none on the reference path)
   serve              decode/prefill budgets per attn_impl
   donation           declared donations appear in the lowering
 

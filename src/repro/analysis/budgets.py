@@ -102,8 +102,14 @@ SERVE_DISPATCH_BUDGETS: Dict[str, Dict[str, int]] = {
     "flash": {"decode": 1, "prefill": 0},
 }
 
-# the fused inner-step scan is pure XLA — no Pallas dispatch ever
-SEGMENT_SCAN_PALLAS_CALLS = 0
+# attn_impl -> exact pallas_call count in the traced segment scan. "ref" is
+# pure XLA. "flash" is the attention kernel inside the layer scan, counted
+# once per appearance in the program: the forward, its recompute under
+# remat, and the fused backward kernel, whatever the depth.
+SEGMENT_SCAN_PALLAS_CALLS: Dict[str, int] = {
+    "ref": 0,
+    "flash": 3,
+}
 
 # ---------------------------------------------------------------------------
 # banned primitives (jitted protocol plane)
@@ -146,10 +152,11 @@ KERNEL_CONTRACTS: Dict[str, KernelContract] = {
     # kernel-gated to a multiple of 256 and the engine dials run <= 1024
     "delta_codec": KernelContract(
         dim_bounds={"rows": 256, "block": 1024, "pb": 1024}),
-    # q tile (bq, hd) vs full-K kv tiles (Sk, hd): Sk bound = the longest
-    # sequence the training configs trace (paper seq lens << 4096)
+    # one (batch, head) per grid step: whole-sequence (sq | sk, hd) tiles
+    # and (1, sq) log-sum-exp rows; sq/sk bound = the longest sequence the
+    # training configs trace (paper seq lens << 4096)
     "flash_attention": KernelContract(
-        dim_bounds={"bq": 128, "bk": 128, "hd": 128, "Sk": 4096}),
+        dim_bounds={"hd": 128, "sq": 4096, "sk": 4096}),
     # per-(b, kv-head) decode: (bc, hd) cache tiles over the ring buffer
     "flash_decode": KernelContract(
         dim_bounds={"bc": 512, "hd": 128, "G": 16}),

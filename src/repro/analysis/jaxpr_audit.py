@@ -253,23 +253,18 @@ def audit_engine(budgets: Optional[Dict] = None) -> List[str]:
     return errors
 
 
-def _segment_fixture(*, donate=None, max_segment: int = 8):
-    """A real SegmentRunner over the tiny dense model — the same single_step
-    shape the trainer builds (loss + AdamW), sized for tracing."""
-    from repro.core.trainer import SegmentRunner
-    from repro.models import api
-    from repro.optim import adamw_init, adamw_update
+def _segment_fixture(*, donate=None, max_segment: int = 8,
+                     attn_impl: str = "ref"):
+    """A real SegmentRunner over the tiny dense model, its step built by
+    the trainer's own `make_single_step` (loss + AdamW), sized for
+    tracing."""
+    from repro.core.trainer import SegmentRunner, make_single_step
+    from repro.optim import adamw_init
     mcfg = _tiny_model()
     stack = _tiny_stack(mcfg, M=2)
     opt = jax.vmap(adamw_init)(stack)
-
-    def single_step(params, opt_state, batch, lr):
-        (loss, _), grads = jax.value_and_grad(
-            lambda p: api.loss_fn(mcfg, p, batch), has_aux=True)(params)
-        params, opt_state = adamw_update(grads, opt_state, params, lr,
-                                         weight_decay=0.1)
-        return params, opt_state, loss
-
+    single_step = make_single_step(mcfg, weight_decay=0.1,
+                                   attn_impl=attn_impl)
     runner = SegmentRunner(single_step, max_segment=max_segment,
                            donate=donate)
     batch_seg = {"tokens": jnp.zeros((4, 2, 2, 8), jnp.int32),
@@ -279,16 +274,21 @@ def _segment_fixture(*, donate=None, max_segment: int = 8):
 
 
 def audit_segment() -> List[str]:
-    """The fused inner-step scan must stay pure XLA (zero Pallas dispatches),
-    callback-free, and f64-free."""
+    """The fused inner-step scan, per attn_impl: exact Pallas dispatch
+    counts (none on the reference path), callback-free, f64-free."""
     errors: List[str] = []
-    runner, stack, opt, batch_seg, lrs = _segment_fixture()
-    jaxpr = jax.make_jaxpr(runner._fn.fn)(stack, opt, batch_seg, lrs).jaxpr
-    label = "trainer.segment_scan"
-    _collect(errors, lambda: check_pallas_budget(
-        jaxpr, budgets_lib.SEGMENT_SCAN_PALLAS_CALLS, label))
-    _collect(errors, lambda: check_banned_primitives(jaxpr, label))
-    _collect(errors, lambda: check_no_f64(jaxpr, label))
+    for attn_impl, budget in sorted(
+            budgets_lib.SEGMENT_SCAN_PALLAS_CALLS.items()):
+        runner, stack, opt, batch_seg, lrs = _segment_fixture(
+            attn_impl=attn_impl)
+        jaxpr = jax.make_jaxpr(runner._fn.fn)(stack, opt, batch_seg,
+                                              lrs).jaxpr
+        label = f"trainer.segment_scan[attn_impl={attn_impl}]"
+        _collect(errors, lambda j=jaxpr, b=budget, l=label:
+                 check_pallas_budget(j, b, l))
+        _collect(errors, lambda j=jaxpr, l=label:
+                 check_banned_primitives(j, l))
+        _collect(errors, lambda j=jaxpr, l=label: check_no_f64(j, l))
     return errors
 
 

@@ -43,7 +43,7 @@ from repro.data.pipeline import (MarkovCorpus, make_worker_streams,
 from repro.models import api
 from repro.optim import adamw_init, adamw_update, warmup_cosine
 from repro.optim.adamw import AdamWState
-from repro.telemetry import Counters, span
+from repro.telemetry import Counters, kernel_notes, span
 
 
 @dataclasses.dataclass
@@ -126,14 +126,19 @@ def _jit_gen_frames():
     return jax.jit(gen, static_argnums=(2, 3, 4))
 
 
-def make_single_step(mcfg: ModelConfig, *, weight_decay: float):
+def make_single_step(mcfg: ModelConfig, *, weight_decay: float,
+                     attn_impl: str = "auto"):
     """One worker's inner step: loss + grad of `api.loss_fn`, then AdamW.
     `(params, opt_state, batch, lr) -> (params, opt_state, loss)`; the
-    trainer vmaps it over the worker axis (and `SegmentRunner` scans it)."""
+    trainer vmaps it over the worker axis (and `SegmentRunner` scans it).
+    `attn_impl` goes through `api.resolve_attn_impl`: the flash kernel
+    with its backward pass on accelerators, the reference on CPU."""
+    attn_impl = api.resolve_attn_impl(attn_impl)
 
     def single_step(params, opt_state, batch, lr):
         (loss, metrics), grads = jax.value_and_grad(
-            lambda p: api.loss_fn(mcfg, p, batch), has_aux=True)(params)
+            lambda p: api.loss_fn(mcfg, p, batch, attn_impl=attn_impl),
+            has_aux=True)(params)
         params, opt_state = adamw_update(grads, opt_state, params, lr,
                                          weight_decay=weight_decay)
         return params, opt_state, loss
@@ -163,7 +168,9 @@ class SegmentRunner:
 
     Each call is the host span `repro.dispatch` (chunk slicing, the scan
     dispatches, the loss concat); every chunk counts one scan dispatch in
-    `telemetry`, the trainer's `Counters` when it built the runner.
+    `telemetry`, the trainer's `Counters` when it built the runner. Each
+    program traced whose layer body took the attention kernel counts one
+    `attention_kernel_layers`.
 
     The power-of-two contract is ENFORCED, not just relied on: the jitted
     scan is wrapped in a `RetraceSentinel` with budget log2(max_segment)+1
@@ -187,8 +194,11 @@ class SegmentRunner:
                 p, o, losses = vstep(carry[0], carry[1], batch, lr)
                 return (p, o), losses
 
-            (p, o), losses = jax.lax.scan(
-                body, (params_stack, opt_state), (batch_seg, lrs))
+            with kernel_notes() as notes:
+                (p, o), losses = jax.lax.scan(
+                    body, (params_stack, opt_state), (batch_seg, lrs))
+            if "attention" in notes:
+                self.telemetry.attention_kernel_layers += 1
             return p, o, losses          # losses: (n, M)
 
         can_donate = (jax.default_backend() != "cpu" if donate is None

@@ -1,47 +1,39 @@
 """Public wrapper: (B, S, H, hd) layout in/out, padding to block multiples,
-interpret-mode on CPU."""
+interpret-mode on CPU. Differentiable: the kernel path carries its own
+backward (flash_attention.py)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import is_cpu
-from repro.kernels.flash_attention.flash_attention import (DEFAULT_BK, DEFAULT_BQ,
-                                                           flash_attention_bhsd)
+from repro.kernels.flash_attention.flash_attention import (flash_attention_bhsd,
+                                                           padded_len)
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
 
-def flash_attention(q, k, v, *, causal=True, window=None, bq=DEFAULT_BQ,
-                    bk=DEFAULT_BK, impl: str = "auto"):
-    """q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd) — the models' layout.
-    Pads S to block multiples, transposes to (B, H, S, hd) for the kernel.
+def flash_attention(q, k, v, *, causal=True, window=None, impl: str = "auto"):
+    """q: (B, S, H, hd); k, v: (B, S, KV, hd) — the models' layout.
+    Pads S to the kernel's block multiple and transposes to (B, H, S, hd)
+    for the kernel, which picks its block sizes from S.
     `impl`: "ref" = pure-jnp oracle; "auto"/"pallas" = Pallas kernel
     (interpret mode on CPU)."""
     if impl not in ("auto", "pallas", "ref"):
         raise ValueError(f"unknown impl {impl!r}; options: auto|pallas|ref")
     if impl == "ref":
         return flash_attention_ref(q, k, v, causal=causal, window=window)
-    B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
-    interpret = is_cpu()
-    bq = min(bq, max(Sq, 8))
-    bk = min(bk, max(Sk, 8))
-    pad_q = (-Sq) % bq
-    pad_k = (-Sk) % bk
-    qt = jnp.moveaxis(q, 2, 1)
-    kt = jnp.moveaxis(k, 2, 1)
-    vt = jnp.moveaxis(v, 2, 1)
-    if pad_q:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-    if pad_k:
-        # padded keys sit at positions >= Sk; causal masking from real queries
-        # (pos < Sq <= Sk) removes them as long as causal=True. For non-causal use
-        # with padding, mask via window instead — asserted here.
-        assert causal, "non-causal flash path requires Sk % bk == 0"
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    S = q.shape[1]
+    if k.shape[1] != S:
+        raise ValueError(f"flash path needs as many keys as queries, got "
+                         f"{k.shape[1]} keys for {S} queries")
+    pad = padded_len(S) - S
+    # padded keys sit at positions >= S; causal masking removes them from
+    # every real query. Without causality they would need a mask of their own
+    if pad and not causal:
+        raise ValueError(f"non-causal flash path cannot pad S={S} to "
+                         f"{S + pad}")
+    qt, kt, vt = (jnp.pad(jnp.moveaxis(x, 2, 1),
+                          ((0, 0), (0, 0), (0, pad), (0, 0)))
+                  for x in (q, k, v))
     o = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                             bq=bq, bk=bk, interpret=interpret)
-    if pad_q:
-        o = o[:, :, :Sq]
-    return jnp.moveaxis(o, 1, 2)
+                             interpret=is_cpu())
+    return jnp.moveaxis(o[:, :, :S], 1, 2)

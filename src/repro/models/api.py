@@ -35,6 +35,18 @@ def family_module(cfg: ModelConfig):
     return _FAMILY_MOD[cfg.family]
 
 
+def resolve_attn_impl(attn_impl: str = "auto") -> str:
+    """"auto" resolves from the backend: the jnp reference on CPU, where
+    interpret-mode Pallas is orders slower (and every CPU golden pins the
+    reference's numbers), the Pallas kernels on accelerators."""
+    if attn_impl == "auto":
+        import jax
+        attn_impl = "ref" if jax.default_backend() == "cpu" else "flash"
+    if attn_impl not in ("ref", "flash"):
+        raise ValueError(f"unknown attn_impl {attn_impl!r}")
+    return attn_impl
+
+
 def init_params(cfg: ModelConfig, key):
     return family_module(cfg).init_params(cfg, key)
 

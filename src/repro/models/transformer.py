@@ -25,6 +25,7 @@ from repro.models.layers import (apply_rope, attn_out, attn_qkv, chunked_cross_e
                                  dense_init, embed_init, gqa_attention, init_attn_params,
                                  rms_norm, swiglu)
 from repro.models.layers import cast_params_for_compute
+from repro.telemetry import note_kernel
 
 
 # ---------------------------------------------------------------------------
@@ -105,6 +106,7 @@ def _layer(cfg: ModelConfig, x, lp, positions, window, attn_impl,
         q, k, v = attn_qkv(h, lp["attn"], cfg, positions)
         if attn_impl == "flash":
             from repro.kernels.flash_attention import ops as flash_ops
+            note_kernel("attention")
             o = flash_ops.flash_attention(q, k, v, causal=True, window=window)
         else:
             o = gqa_attention(q, k, v, causal=True, window=window,

@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.analysis.retrace import RetraceSentinel
 from repro.configs.base import ModelConfig
-from repro.models import transformer
+from repro.models import api, transformer
 from repro.serve import cache as cache_lib
 
 
@@ -129,12 +129,7 @@ class ServeEngine:
                 f"launch/serve.py for SSM/hybrid archs")
         if mode not in self.MODES:
             raise ValueError(f"unknown mode {mode!r}; options: {self.MODES}")
-        if attn_impl == "auto":
-            # interpret-mode Pallas is orders slower than the reference path
-            # on CPU; on real accelerators the kernel is the point
-            attn_impl = "ref" if jax.default_backend() == "cpu" else "flash"
-        if attn_impl not in ("ref", "flash"):
-            raise ValueError(f"unknown attn_impl {attn_impl!r}")
+        attn_impl = api.resolve_attn_impl(attn_impl)
         self.cfg = cfg
         self.params = params
         self.n_slots = int(n_slots)

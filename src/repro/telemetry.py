@@ -12,16 +12,20 @@ inner steps, scan dispatches, protocol initiations and deliveries, blocking
 device-to-host reads by reason, and `boundary_s`, the host's time from the
 return of one segment's last blocking read to the next segment's first scan
 dispatch, while the device has nothing queued. `snapshot()` and
-`difference()` give the counts of an interval.
+`difference()` give the counts of an interval. `attention_kernel_layers`
+is counted at trace time: each compiled segment program whose layer body
+took the Pallas attention kernel (`kernel_notes`, `note_kernel`).
 
 Both are always on. Neither reaches `history` or a checkpoint: timings there
 would break the bitwise kill-and-resume contract.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, Optional, Set
 
 import jax
 
@@ -31,6 +35,29 @@ PREFIX = "repro."
 def span(name: str, **ids) -> jax.profiler.TraceAnnotation:
     """A host span `repro.<name>` carrying `ids` as its event stats."""
     return jax.profiler.TraceAnnotation(PREFIX + name, **ids)
+
+
+_notes: contextvars.ContextVar[Optional[Set[str]]] = contextvars.ContextVar(
+    "repro_kernel_notes", default=None)
+
+
+@contextlib.contextmanager
+def kernel_notes() -> Iterator[Set[str]]:
+    """Collect the names that `note_kernel` records while a program is
+    traced inside this block."""
+    notes: Set[str] = set()
+    token = _notes.set(notes)
+    try:
+        yield notes
+    finally:
+        _notes.reset(token)
+
+
+def note_kernel(name: str) -> None:
+    """Record, at trace time, that a layer took the Pallas kernel `name`."""
+    notes = _notes.get()
+    if notes is not None:
+        notes.add(name)
 
 
 @dataclasses.dataclass
@@ -43,6 +70,7 @@ class Counters:
     initiations: int = 0
     deliveries: int = 0
     boundary_s: float = 0.0
+    attention_kernel_layers: int = 0
     host_syncs: Dict[str, int] = dataclasses.field(default_factory=dict)
     _last_read: Optional[float] = dataclasses.field(default=None, init=False,
                                                     repr=False)
@@ -72,6 +100,7 @@ class Counters:
                 "initiations": self.initiations,
                 "deliveries": self.deliveries,
                 "boundary_s": self.boundary_s,
+                "attention_kernel_layers": self.attention_kernel_layers,
                 "host_syncs": dict(self.host_syncs)}
 
     def line(self) -> str:
@@ -82,7 +111,8 @@ class Counters:
                 f"{self.scan_dispatches} scan dispatches, "
                 f"{sum(syncs.values())} host syncs ({by_reason}), "
                 f"{self.initiations} initiations, {self.deliveries} "
-                f"deliveries, boundary {self.boundary_s:.3f} s host time")
+                f"deliveries, boundary {self.boundary_s:.3f} s host time, "
+                f"attention_kernel_layers {self.attention_kernel_layers}")
 
 
 def difference(after: Dict[str, Any], before: Dict[str, Any]

@@ -95,6 +95,76 @@ def test_flash_attention_first_token_attends_self():
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("B,S,H,KV,hd,window", [
+    (1, 256, 4, 4, 64, None),      # H == KV
+    (1, 256, 8, 2, 64, None),      # GQA group of 4
+    (1, 384, 4, 1, 64, 96),        # GQA + sliding window
+    (2, 200, 4, 1, 32, None),      # non-multiple S (padding path)
+    (1, 1024, 2, 2, 64, 300),      # window across 512-wide query blocks
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_vjp_matches_ref(B, S, H, KV, hd, window, dtype):
+    """dq, dk, dv of the kernel's custom VJP against `jax.vjp` of the
+    reference, on the same output cotangent."""
+    q = rand(1, (B, S, H, hd), dtype)
+    k = rand(2, (B, S, KV, hd), dtype)
+    v = rand(3, (B, S, KV, hd), dtype)
+    do = rand(4, (B, S, H, hd), dtype)
+    _, vjp = jax.vjp(lambda *a: flash_attention(*a, causal=True,
+                                                window=window), q, k, v)
+    _, vjp_ref = jax.vjp(lambda *a: flash_attention_ref(*a, causal=True,
+                                                        window=window), q, k, v)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
+    for got, want in zip(vjp(do), vjp_ref(do)):
+        assert got.dtype == want.dtype
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        scale = np.abs(want).max()
+        np.testing.assert_allclose(got / scale, want / scale, rtol=0, atol=tol)
+
+
+# compute dtype -> (loss rtol, per-leaf gradient gap over its norm): at
+# bf16 the kernel rounds P and dS to bf16 at the MXU where the CPU
+# reference keeps them f32, a ~1% gradient difference
+SINGLE_STEP_TOL = {"float32": (1e-6, 1e-5), "bfloat16": (1e-3, 5e-2)}
+
+
+@pytest.mark.parametrize("compute_dtype", sorted(SINGLE_STEP_TOL))
+def test_single_step_flash_matches_ref(compute_dtype):
+    """The trainer's inner step (vmapped over regions, scanned over steps,
+    layers under remat) with the flash kernel against the reference. At
+    lr 0 the parameters stay put, so the losses of both steps and the AdamW
+    first moment (a sum of the two steps' gradients) compare the loss and
+    its gradient alone."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.core.trainer import SegmentRunner, make_single_step
+    from repro.models import api
+    from repro.optim import adamw_init
+    cfg = dataclasses.replace(get_config("paper_150m").reduced(),
+                              compute_dtype=compute_dtype)
+    loss_tol, grad_tol = SINGLE_STEP_TOL[compute_dtype]
+    M, B, S, n = 2, 2, 128, 2
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    stack = jax.tree.map(lambda a: jnp.stack([a] * M), params)
+    opt = jax.vmap(adamw_init)(stack)
+    tokens = jax.random.randint(KEY, (n, M, B, S + 1), 0, cfg.vocab)
+    batch = {"tokens": tokens[..., :-1], "labels": tokens[..., 1:]}
+    lrs = jnp.zeros((n,), jnp.float32)
+    out = {}
+    for impl in ("ref", "flash"):
+        runner = SegmentRunner(make_single_step(cfg, weight_decay=0.1,
+                                                attn_impl=impl))
+        out[impl] = runner(stack, opt, batch, lrs)
+        want = 1 if impl == "flash" else 0
+        assert runner.telemetry.attention_kernel_layers == want
+    (_, o_ref, loss_ref), (_, o_fl, loss_fl) = out["ref"], out["flash"]
+    np.testing.assert_allclose(loss_fl, loss_ref, rtol=loss_tol)
+    for g_fl, g_ref in zip(jax.tree.leaves(o_fl.mu),
+                           jax.tree.leaves(o_ref.mu)):
+        gap = float(jnp.linalg.norm(g_fl - g_ref))
+        assert gap <= grad_tol * float(jnp.linalg.norm(g_ref)), gap
+
+
 # ---------------------------------------------------------------------------
 # rglru scan
 # ---------------------------------------------------------------------------

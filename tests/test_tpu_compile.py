@@ -11,7 +11,8 @@ program that does not fit HBM. Covered here:
   * the serve engine's decode step over chip_smoke.py's slot plane, with
     flash_decode;
   * the trainer's segment program (the same `single_step` the trainer
-    scans) at paper_150m widths with M=4 regions vmapped on one chip, whose
+    scans, with the flash attention kernel and its backward pass as on the
+    chip) at paper_150m widths with M=4 regions vmapped on one chip, whose
     peak, plus the resident cocodc engine state, must fit 16 GB of HBM.
 
 Only one process at a time may load the TPU library, so the topology is
@@ -117,18 +118,38 @@ def _kernel_cases(cfg, param_shapes):
         "flash_attention": (flash_attention_bhsd, [
             ((1, nh, SEQ, hd), bf16), ((1, nkv, SEQ, hd), bf16),
             ((1, nkv, SEQ, hd), bf16)]),
+        # the trainer's backward: M regions x LOCAL_BATCH sequences
+        "flash_attention_bwd": (_attention_vjp, [
+            ((M * LOCAL_BATCH, nh, SEQ, hd), bf16),
+            ((M * LOCAL_BATCH, nkv, SEQ, hd), bf16),
+            ((M * LOCAL_BATCH, nkv, SEQ, hd), bf16),
+            ((M * LOCAL_BATCH, nh, SEQ, hd), bf16)]),
     }
 
 
+def _attention_vjp(q, k, v, do):
+    """dq, dk, dv of flash attention: the forward (for its residuals) and
+    the backward kernel."""
+    from repro.kernels.flash_attention.flash_attention import \
+        flash_attention_bhsd
+    return jax.vjp(flash_attention_bhsd, q, k, v)[1](do)
+
+
 KERNELS = ("delay_comp_2d", "outer_nesterov", "outer_deliver_blend",
-           "outer_deliver_compensate", "flash_decode", "flash_attention")
+           "outer_deliver_compensate", "flash_decode", "flash_attention",
+           "flash_attention_bwd")
+# kernels each compiled program must hold, where it is more than one
+KERNEL_NAMES = {"flash_attention_bwd": ("flash_attention_fwd",
+                                        "flash_attention_bwd")}
 
 
 @pytest.mark.parametrize("name", KERNELS)
 def test_kernel_compiles_for_v5e(name, cfg, param_shapes, sds):
     fn, args = _kernel_cases(cfg, param_shapes)[name]
-    compiled = _compile(fn, *(sds(s, d) for s, d in args))
-    assert "tpu_custom_call" in compiled.as_text()
+    text = _compile(fn, *(sds(s, d) for s, d in args)).as_text()
+    assert "tpu_custom_call" in text
+    for kernel in KERNEL_NAMES.get(name, ()):
+        assert kernel in text, kernel
 
 
 # bfloat16 is the model's serving dtype; chip_smoke.py also serves at float32
@@ -158,17 +179,24 @@ def _nbytes(tree):
                for x in jax.tree.leaves(tree))
 
 
-def test_paper_150m_segment_fits_v5e_hbm(cfg, param_shapes, sds):
+def test_paper_150m_segment_fits_v5e_hbm(cfg, param_shapes, sds,
+                                        monkeypatch):
+    from repro.kernels.flash_attention import ops as fa_ops
+    # the chip's path: the flash kernel with its backward pass, lowered
+    # (the host backend is the CPU, which would interpret it)
+    monkeypatch.setattr(fa_ops, "is_cpu", lambda: False)
     stack = jax.tree.map(lambda a: sds((M,) + a.shape, a.dtype),
                          param_shapes)
     opt = jax.tree.map(lambda a: sds(a.shape, a.dtype),
                        jax.eval_shape(jax.vmap(adamw_init), stack))
     batch = {k: sds((1, M, LOCAL_BATCH, SEQ), jnp.int32)
              for k in ("tokens", "labels")}
-    runner = SegmentRunner(make_single_step(cfg, weight_decay=0.1),
+    runner = SegmentRunner(make_single_step(cfg, weight_decay=0.1,
+                                            attn_impl="flash"),
                            donate=True)
     compiled = runner._fn.fn.lower(stack, opt, batch,
                                    sds((1,), jnp.float32)).compile()
+    assert "flash_attention_bwd" in compiled.as_text()
     ma = compiled.memory_analysis()
     # the carry is donated: params stack + AdamW moments update in place
     assert ma.alias_size_in_bytes >= _nbytes(stack) + _nbytes(opt)
