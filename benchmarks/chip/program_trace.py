@@ -1,15 +1,15 @@
 #!/usr/bin/env python3
-"""The program's own spans, counters and named scopes, read from one traced
-window of a training cell.
+"""The program's own spans, counters and named scopes, read from a traced
+window.
 
     python3 benchmarks/chip/program_trace.py --workload <cell> --seed <n> \
         --seconds <s>
 
-`run.py` reduces a trace by program, by kernel and by the harness's own
-`bench.*` spans. The trainer now writes its own: host spans `repro.*`
-(`repro.telemetry.span`), host counters (`trainer.telemetry`) and the named
-scopes `embed`, `attention`, `mlp`, `head` and `adamw` in the segment
-program's op metadata. This module reads them:
+The trainer writes host spans `repro.*` (`repro.telemetry.span`), host
+counters (`trainer.telemetry`) and the named scopes `embed`, `attention`,
+`mlp`, `head` and `adamw` in the segment program's op metadata. This module
+reads the spans and scopes of every traced run (`run.py`'s window is this
+`Window`):
 
 - `reduce_program` gives `scopes`, the device seconds and op count of the
   `run_segment` ops in the window by scope, each split into `forward`,
@@ -17,9 +17,10 @@ program's op metadata. This module reads them:
   `idle_gaps_program`, the idle seconds by the innermost `repro.*` span
   open at each gap's midpoint;
 - the command runs one cell once with the trace on, through
-  `train_cell.run` as `run.py` does, and prints both, the counters'
-  difference across the window (`telemetry`) and the readers of the five
-  metrics that read them, beside the cell's own per-layer metrics.
+  `run.run_cell`, and prints the readers of `READERS` beside the cell's own
+  per-layer metrics, with the scopes, the idle seconds by program span and
+  the counters' difference across the window (`telemetry`, which
+  `train_cell` puts into every run's record).
 
 A TPU op event carries its HLO instruction's text without the metadata. Its
 `op_name` (where a fusion carries its root's) is looked up in the HLO proto
@@ -29,21 +30,14 @@ op (`program_op_names`).
 """
 from __future__ import annotations
 
-import time
-
-T_START = time.perf_counter()
-
-import argparse  # noqa: E402
-import bisect  # noqa: E402
-import collections  # noqa: E402
-import glob  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import shutil  # noqa: E402
-import sys  # noqa: E402
-import weakref  # noqa: E402
-from pathlib import Path  # noqa: E402
-from typing import Dict, Iterator, Mapping, Optional, Sequence, Tuple  # noqa
+import argparse
+import bisect
+import collections
+import json
+import re
+import sys
+from pathlib import Path
+from typing import Dict, Iterator, Mapping, Optional, Sequence, Tuple
 
 ROOT = Path(__file__).resolve().parents[2]
 for p in (ROOT, ROOT / "src"):
@@ -55,7 +49,8 @@ from benchmarks.chip import traces  # noqa: E402
 SPAN_PREFIX = "repro."
 SCOPES = ("embed", "attention", "mlp", "head", "adamw")
 READERS = ("attention_ms_per_step", "mlp_ms_per_step", "head_ms_per_step",
-           "adamw_ms_per_step", "boundary_ms_per_segment")
+           "adamw_ms_per_step", "boundary_ms_per_segment",
+           "flash_attention_fwd_roofline", "flash_attention_bwd_roofline")
 SEGMENT_PROGRAM = "run_segment"
 METADATA_PLANE = "/host:metadata"
 _SCOPE_TOKEN = re.compile(r"(?:^|[/(;])(" + "|".join(SCOPES)
@@ -150,19 +145,9 @@ class Capture(traces.Capture):
     """`traces.Capture` that also keeps `op_names`, the programs' op_names
     (`program_op_names`), read before the trace is deleted."""
 
-    def __exit__(self, *exc):
-        import jax
-        try:
-            jax.profiler.stop_trace()
-            paths = glob.glob(f"{self._dir}/**/*.xplane.pb", recursive=True)
-            if exc[0] is None:
-                if not paths:
-                    raise RuntimeError("the profiler wrote no trace")
-                self.events = traces.load_xplane(paths[0])
-                self.op_names = program_op_names(Path(paths[0]).read_bytes())
-        finally:
-            shutil.rmtree(self._dir, ignore_errors=True)
-        return False
+    def read(self, path: str) -> None:
+        super().read(path)
+        self.op_names = program_op_names(Path(path).read_bytes())
 
 
 def scope_of(op_name: str) -> str:
@@ -254,39 +239,31 @@ def reduce_program(events: Sequence[traces.Event],
 
 
 class Window:
-    """The cell's measured window with the profiler on: the standard
-    reduction plus `reduce_program`'s keys, and `telemetry`, the difference
-    of the trainer's counters across the window (None for a trainer that
-    keeps none)."""
+    """A run's measured window. With tracing on: a profiler capture around
+    it (`Capture`), a host span `bench.window` marking its bounds on the
+    trace's clock, and `reduction`, `traces.reduce_events`'s numbers plus
+    `reduce_program`'s; `segment_programs` says for each segment program
+    in the trace whether its HLO op_names were found."""
 
-    def __init__(self, kernels, trainer_ref):
+    def __init__(self, trace: bool, kernels=()):
+        self.trace = trace
         self.kernels = tuple(kernels)
-        self.trainer_ref = trainer_ref
         self.reduction: Optional[Dict] = None
-        self.telemetry: Optional[Dict] = None
-        self.segment_programs: Dict[str, bool] = {}   # name: HLO found
+        self.segment_programs: Dict[str, bool] = {}
         self._cap = Capture()
         self._span = None
-        self._before = None
-
-    def _counters(self):
-        tr = self.trainer_ref()
-        return getattr(tr, "telemetry", None) if tr is not None else None
 
     def __enter__(self):
-        c = self._counters()
-        self._before = c.snapshot() if c is not None else None
-        self._cap.__enter__()
-        self._span = traces.host_span("window")
-        self._span.__enter__()
+        if self.trace:
+            self._cap.__enter__()
+            self._span = traces.host_span("window")
+            self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if not self.trace:
+            return False
         self._span.__exit__(*exc)
-        c = self._counters()
-        if c is not None and exc[0] is None:
-            from repro.telemetry import difference
-            self.telemetry = difference(c.snapshot(), self._before)
         self._cap.__exit__(*exc)       # reads the trace, deletes its files
         if exc[0] is None:
             ev = self._cap.events
@@ -312,88 +289,49 @@ def split_line(scopes: Dict, steps: int) -> str:
     return "; ".join(parts)
 
 
-def run_traced(workload: str, seed: int, seconds: float,
-               require_chip: bool = True, registry=None) -> Dict:
-    """One traced run of a training cell, read as the module docstring
-    says. Returns the result object, which ends the command's output."""
-    import jax
-    from benchmarks.chip import device, run, train_cell
-    from benchmarks.chip.registry import Registry
-    reg = registry or Registry()
-    cell = reg.workload(workload)
-    cfg, mix = reg.config(cell["config"]), reg.traffic(cell["traffic"])
-    if mix["kind"] != "train":
-        raise ValueError(f"{workload} is not a training cell")
-    if require_chip:
-        info = device.guard(int(cell["chips"]))
-    else:
-        d = jax.devices()[0]
-        info = {"platform": d.platform, "kind": d.device_kind,
-                "count": len(jax.devices())}
-    names = [m["name"] for m in reg.metrics_of(workload, "per_layer")]
-    names += [n for n in READERS if n not in names]
-    readers = {n: reg.metric(n) for n in names}
-    kernels = tuple(k for mod in readers.values()
-                    for k in getattr(mod, "KERNELS", ()))
-    held = {"trainer": lambda: None}
-
-    def hold(trainer):
-        held["trainer"] = weakref.ref(trainer)
-
-    class Context(run.Context):
-        def window(self):
-            return Window(self.kernels, held["trainer"])
-
-    ctx = Context(cfg=cfg, mix=mix, seed=int(seed), seconds=float(seconds),
-                  trace=True, chips=int(cell["chips"]), kernels=kernels,
-                  counter=device.CompileCounter(), t_start=T_START,
-                  break_program=hold)
-    res = train_cell.run(ctx)
-    win, rec = res["window"], dict(res["record"])
+def log_window(win: Window, steps: int, log) -> None:
+    """The lines a traced window adds to a run's log: the segment programs
+    found with their op_names, the scopes, the ops under none, and the idle
+    seconds by program span."""
     red = win.reduction
-    if win.telemetry is not None:
-        rec["telemetry"] = win.telemetry
-    view = {"record": rec, "trace": red, "config": cfg, "mix": mix,
-            "peaks": device.peaks_for(info["kind"]) if require_chip else None,
-            "end_to_end": res["end_to_end"]}
-    metrics = {}
-    for name, mod in readers.items():
-        v = mod.read(view)
-        if v is not None:
-            metrics[name] = v
-    steps = rec["steps"]
-    run.log(f"[trace] segment programs with their HLO op_names in the "
-            f"trace: {json.dumps(win.segment_programs)}")
-    run.log(f"[trace] scopes, ms per inner step: "
-            f"{split_line(red['scopes'], steps)}")
+    log(f"[trace] segment programs with their HLO op_names in the "
+        f"trace: {json.dumps(win.segment_programs)}")
+    log(f"[trace] scopes, ms per inner step: "
+        f"{split_line(red['scopes'], steps)}")
     if set(red["scopes"]) == {"other"}:
         # JAX's persistent cache key leaves op metadata out: an executable
         # compiled from a program without the scopes is loaded as it is
-        run.log("[trace] no op under a named scope: were the segment "
-                "programs loaded from a compile cache filled by a program "
-                "without them?")
+        log("[trace] no op under a named scope: were the segment "
+            "programs loaded from a compile cache filled by a program "
+            "without them?")
     seg = red["programs"].get(SEGMENT_PROGRAM, {}).get("s", 0.0)
     in_scopes = sum(d["s"] for d in red["scopes"].values())
-    run.log(f"[trace] ops under no scope, s: {json.dumps(red['other_ops'])}")
-    run.log(f"[trace] scope op time {in_scopes:.4f} s of {seg:.4f} s of "
-            f"segment program; idle by program span "
-            f"{json.dumps(red['idle_gaps_program'])}")
-    if win.telemetry is not None:
-        t = win.telemetry
-        run.log(f"[train] telemetry {json.dumps(t)}; host syncs "
-                f"{sum(t['host_syncs'].values())} by reason "
-                f"{json.dumps(t['host_syncs'])}")
-    checks, correct = run.judge(res["numbers"], reg.limits(workload))
-    return {"correct": bool(correct and res["failed"] == 0),
-            "attempted": int(res["attempted"]), "failed": int(res["failed"]),
-            "end_to_end": res["end_to_end"], "metrics": metrics,
-            "device": {**info, "memory_peak_bytes": res["memory_peak_bytes"],
-                       "busy_s": red["busy_s"], "window_s": red["window_s"]},
-            "breakdown": {"device_ops": red["device_ops"],
-                          "idle_gaps": red["idle_gaps"],
-                          "idle_gaps_program": red["idle_gaps_program"],
-                          "scopes": red["scopes"]},
-            "telemetry": rec.get("telemetry"), "checks": checks}
+    log(f"[trace] ops under no scope, s: {json.dumps(red['other_ops'])}")
+    log(f"[trace] scope op time {in_scopes:.4f} s of {seg:.4f} s of "
+        f"segment program; idle by program span "
+        f"{json.dumps(red['idle_gaps_program'])}")
+
+
+def run_traced(workload: str, seed: int, seconds: float,
+               require_chip: bool = True, registry=None) -> Dict:
+    """One traced run of a cell through `run.run_cell`, with the readers of
+    `READERS` besides the cell's own per-layer metrics. Returns the result
+    object, which ends the command's output: the run's line with each
+    metric's bare value, the end-to-end numbers, the scopes and the idle
+    seconds by program span in `breakdown`, and `telemetry`."""
+    from benchmarks.chip import run
+    seen: Dict = {}
+    out = run.run_cell(workload, seed, seconds, True, registry=registry,
+                       require_chip=require_chip, extra_metrics=READERS,
+                       details=seen)
+    red = seen["trace"]
+    return {"correct": out["correct"], "attempted": out["attempted"],
+            "failed": out["failed"], "end_to_end": seen["end_to_end"],
+            "metrics": {k: m["value"] for k, m in out["metrics"].items()},
+            "device": out["device"],
+            "breakdown": {**out["breakdown"], "scopes": red["scopes"]},
+            "telemetry": seen["record"].get("telemetry"),
+            "checks": out["checks"]}
 
 
 def main(argv=None) -> int:

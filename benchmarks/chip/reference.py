@@ -7,9 +7,32 @@ training its own replica with AdamW from weights made here from the seed, and
 the CoCoDC protocol between them (paper Algorithm 1), at the steps the run's
 host scheduler chose.
 
-The model: token embedding, `L` pre-norm blocks of RMSNorm, grouped query
-attention with rotary positions (rotate-half form), RMSNorm and a SwiGLU MLP,
-then a final RMSNorm and a tied or untied output head.
+Two halves. The protocol (`adamw_step`, `fragments`, `CoCoDC`, `train`) is
+generic over the parameter pytree and takes the model as a module. The
+dense model below is the default, for a configuration without a
+`"reference"` key: token embedding, `L` pre-norm blocks of RMSNorm, grouped
+query attention with rotary positions (rotate-half form), RMSNorm and a
+SwiGLU MLP, then a final RMSNorm and a tied or untied output head.
+
+A configuration with `"reference": "<name>"` brings its own model as
+`references/<name>.py` (`Registry.reference`), a module that imports
+nothing of the program and exports, as this one does:
+
+  * `dims(cfg)`: its sizes, with at least `L`, `H` (query heads) and `hd`
+    (head size), which the causal attention count in `flops.py` and the
+    roofline readers use;
+  * `init_params(cfg, key)`: float32 weights from the seed's key, the
+    program's own for the same key, in a pytree whose leaf paths are the
+    program's; leaves stacked by layer sit under `['layers']`, which
+    `fragments` splits by row;
+  * `loss(cfg, params, tokens, labels, lowp)`: the mean next-token NLL of a
+    (B, S) batch, every matmul through `_mm` so that `lowp="fp8"` is the
+    control;
+  * `matmul_params_per_token(cfg)`: the weights one token multiplies with
+    (an MoE layer counts its router and `top_k` experts);
+  * `program_fields(cfg)`: {attribute path of the program's `ModelConfig`:
+    value}, every size the program has to agree on (`train_cell.
+    model_config` refuses a path it cannot resolve and any disagreement).
 
 Every matmul goes through `_mm`, which runs at `Precision.HIGHEST` (a float32
 matmul on the TPU otherwise rounds its inputs to bfloat16). With
@@ -20,6 +43,7 @@ configurations state.
 from __future__ import annotations
 
 import math
+from types import ModuleType
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -57,6 +81,27 @@ def dims(cfg: Dict) -> Dict:
         "eps": float(cfg["rms_norm_eps"]), "theta": float(cfg["rope_theta"]),
         "tied": bool(cfg["tie_word_embeddings"]),
     }
+
+
+def program_fields(cfg: Dict) -> Dict:
+    """The program's `ModelConfig` fields this model fixes; `moe` None, so
+    a program with sparse experts is refused against a dense file."""
+    m = dims(cfg)
+    return {"n_layers": m["L"], "d_model": m["D"], "n_heads": m["H"],
+            "n_kv_heads": m["KV"], "resolved_head_dim": m["hd"],
+            "d_ff": m["F"], "vocab": m["V"], "rms_eps": m["eps"],
+            "rope_theta": m["theta"], "tie_embeddings": m["tied"],
+            "moe": None}
+
+
+def matmul_params_per_token(cfg: Dict) -> int:
+    """Weights each token multiplies with: attention projections, the
+    SwiGLU MLP and the output head. The embedding lookup is a gather and
+    counts nothing."""
+    m = dims(cfg)
+    D, F, H, KV, hd = m["D"], m["F"], m["H"], m["KV"], m["hd"]
+    attn = D * H * hd * 2 + D * KV * hd * 2
+    return m["L"] * (attn + 3 * D * F) + D * m["V"]
 
 
 def _dense(key, shape, fan_in):
@@ -182,13 +227,13 @@ def norms(tree) -> Dict[str, float]:
             for p, a in flat}
 
 
-def adamw_step(cfg: Dict, run: Dict, lowp: Optional[str] = None,
-               batch_frac: float = 1.0):
-    """One jitted AdamW step (b1 0.9, b2 0.95, eps 1e-8, decoupled decay,
-    clip by global norm 1): `(p, mu, nu, tokens, labels, count, lr) ->
-    (p, mu, nu, loss, norms of the clipped gradient)`. `batch_frac` < 1
-    keeps only that leading share of the batch's rows (a planted fault,
-    never used for the reference itself)."""
+def adamw_step(model: ModuleType, cfg: Dict, run: Dict,
+               lowp: Optional[str] = None, batch_frac: float = 1.0):
+    """One jitted AdamW step of `model`'s loss (b1 0.9, b2 0.95, eps 1e-8,
+    decoupled decay, clip by global norm 1): `(p, mu, nu, tokens, labels,
+    count, lr) -> (p, mu, nu, loss, norms of the clipped gradient)`.
+    `batch_frac` < 1 keeps only that leading share of the batch's rows (a
+    planted fault, never used for the reference itself)."""
     b1, b2, eps = 0.9, 0.95, 1e-8
     wd, clip = float(run["weight_decay"]), 1.0
 
@@ -197,7 +242,7 @@ def adamw_step(cfg: Dict, run: Dict, lowp: Optional[str] = None,
             keep = max(1, int(tok.shape[0] * batch_frac))
             tok, lab = tok[:keep], lab[:keep]
         val, g = jax.value_and_grad(
-            lambda q: loss(cfg, q, tok, lab, lowp))(p)
+            lambda q: model.loss(cfg, q, tok, lab, lowp))(p)
         gn = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(g)))
         g = jax.tree.map(lambda x: x * jnp.minimum(1.0, clip / (gn + 1e-9)), g)
         mu = jax.tree.map(lambda m_, x: b1 * m_ + (1 - b1) * x, mu, g)
@@ -310,10 +355,11 @@ def _set(a, idx, v):
     return v if idx is Ellipsis else a.at[idx].set(v)
 
 
-def train(cfg: Dict, params0, batches, mix: Dict, syncs, marks: Sequence[int],
-          lowp: Optional[str] = None, batch_frac: float = 1.0,
-          exchange: bool = True) -> Dict:
-    """M regions trained together for T steps from `params0`.
+def train(model: ModuleType, cfg: Dict, params0, batches, mix: Dict, syncs,
+          marks: Sequence[int], lowp: Optional[str] = None,
+          batch_frac: float = 1.0, exchange: bool = True) -> Dict:
+    """M regions of `model` (a model module; this one for the dense model)
+    trained together for T steps from `params0`.
 
     batches[m][t] = (tokens, labels) of region m at step t. `syncs` lists
     (fragment, t_init, t_deliver): at the end of step t every delivery due
@@ -323,7 +369,7 @@ def train(cfg: Dict, params0, batches, mix: Dict, syncs, marks: Sequence[int],
     `exchange=False` is a planted fault: the outer step runs but no region
     receives its result."""
     M, T = len(batches), len(batches[0])
-    step = adamw_step(cfg, mix, lowp, batch_frac)
+    step = adamw_step(model, cfg, mix, lowp, batch_frac)
     treedef = jax.tree_util.tree_structure(params0)
     regions = []
     for _ in range(M):

@@ -2,9 +2,11 @@
 
 A configuration is `configs/<name>.json`, a traffic mix `traffic/<name>.json`,
 a per-layer metric `metrics/<name>.py` (a module with `read(run) -> float |
-None`), and the limits of a cell's `correct` `limits/<cell>.json`. Each is
-looked up in the search roots in order (the benchmark's own directory first),
-so a later cell adds files and edits none.
+None`), the plain reference model a configuration names under its key
+`"reference"` `references/<name>.py` (the contract is in `reference.py`'s
+docstring), and the limits of a cell's `correct` `limits/<cell>.json`. Each
+is looked up in the search roots in order (the benchmark's own directory
+first), so a later cell adds files and edits none.
 """
 from __future__ import annotations
 
@@ -45,16 +47,31 @@ class Registry:
     def limits(self, cell: str) -> Dict:
         return self._json("limits", cell)
 
+    def _module(self, sub: str, name: str) -> ModuleType:
+        path = self._find(sub, name, ".py")
+        spec = importlib.util.spec_from_file_location(
+            f"chipbench_{sub}_{name.replace('.', '_')}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
     def metric(self, name: str) -> ModuleType:
         """The reader module of a per-layer metric: `read(run)` returns the
         value or None when the run holds nothing to read; an optional
         `KERNELS` tuple names kernels the trace reduction must sum."""
-        path = self._find("metrics", name, ".py")
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_metric_{name.replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return self._module("metrics", name)
+
+    def reference(self, name: str) -> ModuleType:
+        """The plain reference model module `references/<name>.py`."""
+        return self._module("references", name)
+
+    def model(self, cfg: Dict) -> ModuleType:
+        """The reference model of a configuration: the module its
+        `"reference"` key names, else the dense model of `reference.py`."""
+        if "reference" in cfg:
+            return self.reference(cfg["reference"])
+        from benchmarks.chip import reference
+        return reference
 
     def benchmark(self) -> Dict:
         return json.loads(self.benchmark_path.read_text())

@@ -13,8 +13,10 @@ non-zero and prints no result unless JAX sees a TPU of a kind in
 The last line of standard output is one JSON object: `correct`,
 `attempted`, `failed`, `metrics` (the cell's end-to-end metrics with
 `--trace 0`, its per-layer metrics with `--trace 1`), `device`, with
-`--trace 1` a `breakdown`, and last `checks`, each compared number with its
-limit. Earlier lines carry counts and simulated seconds, labelled as such.
+`--trace 1` a `breakdown` (the device ops with most time, and the idle gaps
+by the program's `repro.*` span open in each), and last `checks`, each
+compared number with its limit. Earlier lines carry counts and simulated
+seconds, labelled as such.
 """
 from __future__ import annotations
 
@@ -23,50 +25,23 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Any, Callable, Dict, Optional  # noqa: E402
+from typing import Any, Callable, Dict, Optional, Sequence  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[2]
 for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from benchmarks.chip import device, traces  # noqa: E402
+from benchmarks.chip import device, program_trace  # noqa: E402
 from benchmarks.chip.registry import Registry  # noqa: E402
 
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-class Window:
-    """The measured window; with tracing on, a profiler capture around it
-    and a host span marking its bounds on the trace's clock."""
-
-    def __init__(self, trace: bool, kernels=()):
-        self.trace = trace
-        self.kernels = tuple(kernels)
-        self.reduction: Optional[Dict] = None
-        self._stack = contextlib.ExitStack()
-        self._cap = None
-
-    def __enter__(self):
-        if self.trace:
-            self._cap = self._stack.enter_context(traces.Capture())
-            self._stack.enter_context(traces.host_span("window"))
-        return self
-
-    def __exit__(self, *exc):
-        self._stack.__exit__(*exc)
-        if self.trace and exc[0] is None:
-            ev = self._cap.events
-            self.reduction = traces.reduce_events(
-                ev, traces.window_from_spans(ev, "window"), self.kernels)
-        return False
 
 
 @dataclasses.dataclass
@@ -79,13 +54,14 @@ class Context:
     chips: int
     kernels: tuple
     counter: Any
+    model: Any                        # the reference model module
     log: Callable[[str], None] = log
     t_start: float = T_START
     break_program: Optional[Callable] = None
     control: bool = False
 
-    def window(self) -> Window:
-        return Window(self.trace, self.kernels)
+    def window(self) -> program_trace.Window:
+        return program_trace.Window(self.trace, self.kernels)
 
     def memory_peak(self) -> int:
         return device.memory_peak_bytes(self.chips)
@@ -104,13 +80,17 @@ def judge(numbers: Dict[str, float], limits: Dict):
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              registry: Optional[Registry] = None, require_chip: bool = True,
              break_program: Optional[Callable] = None,
-             control: bool = False) -> Dict:
+             control: bool = False, extra_metrics: Sequence[str] = (),
+             details: Optional[Dict] = None) -> Dict:
     """One run of a cell; returns the result object. `require_chip=False`
     and `break_program` exist for the tests, which drive a run on the CPU
     with the timed path broken underneath; `control` adds the readings of
     the control and of the planted faults (`control.py`), each judged
     against the cell's limits, under `"control"`; the benchmark's own runs
-    never compute them."""
+    never compute them. With `trace`, `extra_metrics` names readers to run
+    besides the cell's own per-layer metrics, and `details`, where given,
+    receives what they read: the run's `record`, the `trace` reduction and
+    the `end_to_end` numbers."""
     import jax
     reg = registry or Registry()
     cell = reg.workload(workload)
@@ -124,14 +104,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         d = jax.devices()[0]
         info = {"platform": d.platform, "kind": d.device_kind,
                 "count": len(jax.devices())}
-    readers = {m["name"]: reg.metric(m["name"]) for m in per_layer} \
-        if trace else {}
+    names = [m["name"] for m in per_layer]
+    names += [n for n in extra_metrics if n not in names]
+    readers = {n: reg.metric(n) for n in names} if trace else {}
     kernels = tuple(k for mod in readers.values()
                     for k in getattr(mod, "KERNELS", ()))
+    model = reg.model(cfg)
     ctx = Context(cfg=cfg, mix=mix, seed=int(seed), seconds=float(seconds),
                   trace=bool(trace), chips=int(cell["chips"]),
                   kernels=kernels, counter=device.CompileCounter(),
-                  break_program=break_program, control=control)
+                  model=model, break_program=break_program, control=control)
     if mix["kind"] != "train":
         raise ValueError(f"unknown traffic kind {mix['kind']!r}")
     from benchmarks.chip import train_cell
@@ -146,20 +128,25 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     dev = {**info, "memory_peak_bytes": res["memory_peak_bytes"]}
     breakdown = None
     if trace:
-        red = res["window"].reduction
+        win = res["window"]
+        red = win.reduction
         ctx_run = {"record": res["record"], "trace": red, "config": cfg,
-                   "mix": mix, "peaks": device.peaks_for(info["kind"])
+                   "mix": mix, "dims": model.dims(cfg),
+                   "peaks": device.peaks_for(info["kind"])
                    if require_chip else None,
                    "end_to_end": res["end_to_end"]}
+        if details is not None:
+            details.update(ctx_run)
         for name, mod in readers.items():
             v = mod.read(ctx_run)
             if v is not None:
-                metrics[name] = {"value": v, "unit": units[name]}
+                metrics[name] = {"value": v, "unit": units.get(name, "")}
         dev.update(busy_s=red["busy_s"], window_s=red["window_s"])
         breakdown = {"device_ops": red["device_ops"],
-                     "idle_gaps": red["idle_gaps"]}
+                     "idle_gaps": red["idle_gaps_program"][:10]}
         log(f"[trace] programs {json.dumps(red['programs'])}")
         log(f"[trace] kernels {json.dumps(red['kernels'])}")
+        program_trace.log_window(win, res["record"]["steps"], log)
 
     checks, correct = judge(res["numbers"], limits)
     correct = correct and res["failed"] == 0

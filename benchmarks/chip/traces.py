@@ -28,7 +28,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-HOST_SPAN_PREFIX = "bench."          # host spans the harness writes
+HOST_SPAN_PREFIX = "bench."          # the harness's window span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,10 +87,14 @@ class Capture:
             if exc[0] is None:
                 if not paths:
                     raise RuntimeError("the profiler wrote no trace")
-                self.events = load_xplane(paths[0])
+                self.read(paths[0])
         finally:
             shutil.rmtree(self._dir, ignore_errors=True)
         return False
+
+    def read(self, path: str) -> None:
+        """Keep what is needed of the trace file before it is deleted."""
+        self.events = load_xplane(path)
 
 
 @contextlib.contextmanager
@@ -132,9 +136,9 @@ def reduce_events(events: Sequence[Event], window_ns: Tuple[float, float],
     busy_s: union of op intervals per chip, inside the window, averaged over
     chips. programs: {name: {"s", "n"}} from module events that start in the
     window, summed over chips. kernels: {name: {"s", "n"}} for op events whose
-    name contains one of `kernels`. device_ops: the 10 op names with most
-    device time. idle_gaps: idle seconds (averaged over chips) by the host
-    span open at each gap's midpoint, 10 largest."""
+    instruction name contains one of `kernels`. device_ops: the 10 op names
+    with most device time. idle_gaps: idle seconds (averaged over chips) by
+    the host span open at each gap's midpoint, 10 largest."""
     lo, hi = window_ns
     chips = sorted({e.plane for e in events if DEVICE_PLANE.match(e.plane)})
     ops = [e for e in events if e.plane in chips and e.line == OPS_LINE]
@@ -167,8 +171,9 @@ def reduce_events(events: Sequence[Event], window_ns: Tuple[float, float],
         key = _op_key(e.name)
         if key not in CONTAINERS:          # their bodies' ops are counted
             by_op[key] += e.dur_ns / 1e9
+        instruction = e.name.split(" = ")[0]   # not its operands' names
         for k in kernels:
-            if k in e.name:
+            if k in instruction:
                 f = found.setdefault(k, {"s": 0.0, "n": 0})
                 f["s"] += e.dur_ns / 1e9
                 f["n"] += 1

@@ -13,13 +13,13 @@ from __future__ import annotations
 import collections
 import dataclasses
 import gc
+import json
 import time
 from typing import Dict, List
 
 import numpy as np
 
 from benchmarks.chip import flops, reference
-from benchmarks.chip.traces import host_span
 
 B1 = 0.9                              # the inner AdamW's first-moment decay
 
@@ -30,21 +30,48 @@ def program_seed(seed: int) -> int:
     return int(seed) % (2 ** 31 - 2 ** 20)
 
 
-def model_config(cfg: Dict):
+def _override(obj, overrides: Dict):
+    """`dataclasses.replace` of `obj` with `overrides`, where a nested object
+    replaces fields of a dataclass field (`{"moe": {"num_experts": 10}}`)."""
+    fields = {}
+    for key, value in overrides.items():
+        if isinstance(value, dict):
+            inner = getattr(obj, key, None)
+            if not dataclasses.is_dataclass(inner):
+                raise ValueError(f"override {key!r}: {inner!r} has no fields")
+            value = _override(inner, value)
+        fields[key] = value
+    return dataclasses.replace(obj, **fields)
+
+
+_MISSING = object()
+
+
+def _field(obj, path: str):
+    """The value at a dotted attribute path, or `_MISSING`."""
+    for part in path.split("."):
+        obj = getattr(obj, part, _MISSING) if obj is not None else _MISSING
+        if obj is _MISSING:
+            return _MISSING
+    return obj
+
+
+def model_config(cfg: Dict, model=reference):
     """The program's ModelConfig for a configuration file, checked against
-    the file's own sizes so the program and the reference run one model."""
+    every field the reference model fixes (`model.program_fields`), so the
+    program and the reference run one model."""
     from repro.configs import get_config
-    mcfg = dataclasses.replace(get_config(cfg["arch"]), **cfg["overrides"])
-    m = reference.dims(cfg)
-    got = {"L": mcfg.n_layers, "D": mcfg.d_model, "H": mcfg.n_heads,
-           "KV": mcfg.n_kv_heads, "hd": mcfg.resolved_head_dim,
-           "F": mcfg.d_ff, "V": mcfg.vocab, "eps": mcfg.rms_eps,
-           "theta": mcfg.rope_theta, "tied": mcfg.tie_embeddings}
-    bad = {k: (got[k], m[k]) for k in got if got[k] != m[k]}
-    if mcfg.moe is not None:
-        bad["moe"] = (mcfg.moe, None)
+    mcfg = _override(get_config(cfg["arch"]), cfg["overrides"])
+    want = model.program_fields(cfg)
+    got = {k: _field(mcfg, k) for k in want}
+    unknown = [k for k, v in got.items() if v is _MISSING]
+    if unknown:
+        raise ValueError(f"{cfg['name']}: the program's config has no "
+                         f"field {unknown}")
+    bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:
-        raise ValueError(f"{cfg['name']}: program and file disagree {bad}")
+        raise ValueError(f"{cfg['name']}: program and file disagree "
+                         f"(field: (program, file)) {bad}")
     if mcfg.compute_dtype != cfg["torch_dtype"] or \
             mcfg.param_dtype != cfg["param_dtype"]:
         raise ValueError(f"{cfg['name']}: dtypes differ from the file")
@@ -177,15 +204,16 @@ def worst_leaf_gap(prog: Dict, ref: Dict, ref_grad: Dict) -> Dict:
     return {"gap": worst, "leaf": where, "skipped": sorted(set(skipped))}
 
 
-def reference_readings(cfg: Dict, mix: Dict, seed: int, batches: List,
-                       syncs: List, marks, **fault) -> Dict:
-    """The reference's losses, first-gradient norms and change norms after
-    each step count in `marks`, from weights made from the seed, through
-    the same protocol events."""
+def reference_readings(model, cfg: Dict, mix: Dict, seed: int,
+                       batches: List, syncs: List, marks, **fault) -> Dict:
+    """The reference model's losses, first-gradient norms and change norms
+    after each step count in `marks`, from weights made from the seed,
+    through the same protocol events."""
     import jax
-    params0 = jax.jit(lambda k: reference.init_params(cfg, k))(
+    params0 = jax.jit(lambda k: model.init_params(cfg, k))(
         jax.random.PRNGKey(program_seed(seed)))
-    return reference.train(cfg, params0, batches, mix, syncs, marks, **fault)
+    return reference.train(model, cfg, params0, batches, mix, syncs, marks,
+                           **fault)
 
 
 def compare(prog: Dict, ref: Dict, n_checked: int, n_synced: int
@@ -211,11 +239,13 @@ CONTROLS = {"fp8": {"lowp": "fp8"}, "half_batch": {"batch_frac": 0.5},
 
 def run(ctx) -> Dict:
     """One run of a training cell. `ctx` gives cfg, mix, seed, seconds,
-    trace (bool), log, t_start, chips, and the hooks a test may set."""
+    trace (bool), log, t_start, chips, the reference model module, and the
+    hooks a test may set."""
     import jax
+    from repro.telemetry import difference
 
-    cfg, mix, log = ctx.cfg, ctx.mix, ctx.log
-    mcfg = model_config(cfg)
+    cfg, mix, log, model = ctx.cfg, ctx.mix, ctx.log, ctx.model
+    mcfg = model_config(cfg, model)
     M, B, S = mix["num_workers"], mix["local_batch"], mix["seq_len"]
     n_checked, n_sync = int(mix["checked_steps"]), int(
         mix["checked_deliveries"])
@@ -267,20 +297,20 @@ def run(ctx) -> Dict:
     log(f"[train] set-up: {warm_steps} steps, fragments initiated "
         f"{sorted(proto.initiated)} delivered {sorted(proto.delivered)}, "
         f"segment programs {rec.trace_count}")
-    if ctx.trace:
-        _install_spans(trainer, rec)
 
     # -- the window -----------------------------------------------------------
     setup_s = time.perf_counter() - ctx.t_start
     n0, d0, syncs0 = proto.n_init, proto.n_deliver, trainer.engine.n_syncs
     rec.losses = []
     ctx.counter.arm()
+    counters0 = trainer.telemetry.snapshot()
     with ctx.window() as win:
         t0 = time.perf_counter()
         lengths = []
         while time.perf_counter() - t0 < ctx.seconds:
             lengths.append(segment(trainer, proto, mix["total_steps"]))
         window_s = time.perf_counter() - t0
+    telemetry = difference(trainer.telemetry.snapshot(), counters0)
     steps = sum(lengths)
     ctx.counter.disarm()
     win_losses = np.concatenate([np.asarray(x) for x in rec.losses])
@@ -288,7 +318,7 @@ def run(ctx) -> Dict:
     n_init, n_deliver = proto.n_init - n0, proto.n_deliver - d0
     mem = ctx.memory_peak()
     stats = trainer.engine.stats()
-    fpt = flops.train_flops_per_token(cfg, S)
+    fpt = flops.train_flops_per_token(cfg, S, model)
     tok = steps * M * B * S
     log(f"[train] window: {steps} steps, {tok} tokens in {window_s:.4f} s; "
         f"{n_init} initiations, {n_deliver} deliveries, "
@@ -297,6 +327,7 @@ def run(ctx) -> Dict:
     log(f"[train] programs lowered in the window {ctx.counter.lowered}, "
         f"compiled {ctx.counter.compiled} (count; should be 0)")
     log(f"[train] peak HBM {mem} B; model FLOPs/token {fpt:.6g}")
+    log(f"[train] telemetry {json.dumps(telemetry)}")
     log(f"[train] simulated WAN (virtual clock, not measured): wall "
         f"{stats['wall_clock_s']:.1f} s, comm {stats['comm_seconds']:.1f} s, "
         f"overlap {stats['overlap_ratio']:.3f}")
@@ -306,7 +337,8 @@ def run(ctx) -> Dict:
     # -- the reference, once the window has closed and the state is freed ----
     t_ref = time.perf_counter()
     marks = (n_checked, n_synced)
-    ref = reference_readings(cfg, mix, ctx.seed, batches, syncs, marks)
+    ref = reference_readings(model, cfg, mix, ctx.seed, batches, syncs,
+                             marks)
     numbers = compare(prog, ref, n_checked, n_synced)
     for n in marks:
         w = worst_leaf_gap(prog["change"][n], ref["change"][n], ref["grad"])
@@ -323,7 +355,7 @@ def run(ctx) -> Dict:
     control = None
     if ctx.control:
         control = {name: compare(reference_readings(
-            cfg, mix, ctx.seed, batches, syncs, marks, **fault), ref,
+            model, cfg, mix, ctx.seed, batches, syncs, marks, **fault), ref,
             n_checked, n_synced) for name, fault in CONTROLS.items()}
     return {
         "control": control,
@@ -333,32 +365,8 @@ def run(ctx) -> Dict:
         "record": {"steps": steps, "tokens": tok, "window_s": window_s,
                    "flops_per_token": fpt, "initiations": n_init,
                    "deliveries": n_deliver, "warm_steps": warm_steps,
-                   "lowered_in_window": ctx.counter.lowered},
+                   "lowered_in_window": ctx.counter.lowered,
+                   "telemetry": telemetry},
         "memory_peak_bytes": mem,
         "window": win,
     }
-
-
-def _install_spans(trainer, rec):
-    """Host spans for the traced run: the scan dispatch, the protocol hook
-    and the data generator, around the calls into each layer."""
-    inner = rec.call
-
-    def scan(*a):
-        with host_span("segment_scan"):
-            return inner(*a)
-    rec.call = scan
-    step_end = trainer.engine.on_step_end
-
-    def on_step_end(*a):
-        with host_span("protocol"):
-            return step_end(*a)
-    trainer.engine.on_step_end = on_step_end
-    for s in trainer.streams:
-        seg = s.segment
-
-        def segment(*a, _seg=seg):
-            with host_span("data"):
-                return _seg(*a)
-        s.segment = segment
-
