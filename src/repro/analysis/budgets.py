@@ -166,4 +166,11 @@ KERNEL_CONTRACTS: Dict[str, KernelContract] = {
     "rwkv6_scan": KernelContract(dim_bounds={"bt": 128, "hd": 128}),
     # flat fragment plane: (BLOCK_ROWS=256, LANES=1024) f32 tiles
     "outer_update": KernelContract(dim_bounds={"block": 256}),
+    # (TM=128 or tm=TM_T=256, tk) row tiles against (tk, tn) weight tiles;
+    # tk and tn are a whole width up to TILE_CAP=1536 (Granite's d_model). The widest
+    # weight tile is double-buffered beside its f32 accumulator, so the
+    # kernels raise their VMEM limit to 64 MiB
+    "grouped_matmul": KernelContract(
+        dim_bounds={"tk": 1536, "tn": 1536, "tm": 256},
+        vmem_budget_bytes=24 * 1024 * 1024),
 }

@@ -13,12 +13,38 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """Sparse experts of every layer. The router is `num_experts` wide and
+    picks `top_k`; the layer is dropless (`models/moe.py`): every routed
+    (token, expert) pair of an expert held here is computed.
+
+    Under expert parallelism `expert_shards` chips divide a layer's experts
+    evenly and this chip, `expert_shard`, holds experts
+    `[expert_shard * E / expert_shards, (expert_shard + 1) * E /
+    expert_shards)`; it still routes over all `num_experts` and computes the
+    part of the result its own experts give."""
     num_experts: int
     top_k: int
-    # capacity factor only matters for dropping implementations; the dense-dispatch
-    # einsum path used here never drops tokens.
     router_jitter: float = 0.0
     load_balance_coef: float = 0.01
+    expert_shards: int = 1
+    expert_shard: int = 0
+
+    def __post_init__(self):
+        if self.num_experts % self.expert_shards or \
+                not 0 <= self.expert_shard < self.expert_shards:
+            raise ValueError(
+                f"expert shard {self.expert_shard} of {self.expert_shards} "
+                f"cannot hold an even share of {self.num_experts} experts")
+
+    @property
+    def held(self) -> int:
+        """Experts held here."""
+        return self.num_experts // self.expert_shards
+
+    @property
+    def first_held(self) -> int:
+        """Global index of the first expert held here."""
+        return self.expert_shard * self.held
 
 
 @dataclass(frozen=True)
@@ -69,10 +95,38 @@ class ModelConfig:
     # dtype policy
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
+    # muP-style scalars (Granite): x0 = embedding_multiplier * embed(tokens);
+    # each residual branch is scaled by residual_multiplier; attention scores
+    # are q.k * attention_multiplier (None: 1/sqrt(head_dim)); logits are
+    # divided by logits_scaling. The defaults are the identity and add no op.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
+
+    @property
+    def scalars_are_identity(self) -> bool:
+        """True when none of the muP scalars changes the model."""
+        return (self.embedding_multiplier == 1.0
+                and self.attention_multiplier is None
+                and self.residual_multiplier == 1.0
+                and self.logits_scaling == 1.0)
+
+    @property
+    def layers_in_place(self) -> bool:
+        """Whether training reads each layer's weights from the stack in
+        place and keeps the stack and its AdamW moments in their stored,
+        region-major layout through a segment (`transformer.forward`,
+        `trainer.SegmentRunner`). Scanning over the stack instead lets the
+        compiler carry a layer-major copy of every layered weight and moment
+        beside the stored ones for the whole segment. An MoE model takes it:
+        its expert stacks' copies did not fit one chip. A dense model keeps
+        the scan, which spares it a relayout of its weights every step."""
+        return self.moe is not None
 
     @property
     def is_attention_free(self) -> bool:

@@ -25,9 +25,10 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Set
 
 import jax
+from jax.experimental.layout import Layout, with_layout_constraint
 import jax.numpy as jnp
 
 from repro.analysis.retrace import RetraceSentinel
@@ -146,6 +147,13 @@ def make_single_step(mcfg: ModelConfig, *, weight_decay: float,
     return single_step
 
 
+def _stored_layout(tree):
+    """Every leaf in its stored row-major layout, the regions axis major."""
+    return jax.tree.map(
+        lambda a: with_layout_constraint(a, Layout(tuple(range(a.ndim)))),
+        tree)
+
+
 class SegmentRunner:
     """Fused inner-step executor: scans `single_step` (vmapped over the worker
     axis) across a stacked batch segment, carrying (params_stack, opt_state)
@@ -170,7 +178,13 @@ class SegmentRunner:
     dispatches, the loss concat); every chunk counts one scan dispatch in
     `telemetry`, the trainer's `Counters` when it built the runner. Each
     program traced whose layer body took the attention kernel counts one
-    `attention_kernel_layers`.
+    `attention_kernel_layers`; each chunk dispatched to a program whose MoE
+    layer took the grouped-matmul kernels counts one
+    `expert_matmul_kernel_layers`, so a window's count shows the kernels ran.
+
+    `layers_in_place` (the model's `ModelConfig.layers_in_place`) holds the
+    scan's carry in its stored layout, so the compiler keeps no second,
+    layer-major copy of the layered weights and moments for the segment.
 
     The power-of-two contract is ENFORCED, not just relied on: the jitted
     scan is wrapped in a `RetraceSentinel` with budget log2(max_segment)+1
@@ -182,23 +196,27 @@ class SegmentRunner:
 
     def __init__(self, single_step, *, max_segment: int = 64,
                  donate: bool | None = None,
-                 telemetry: Optional[Counters] = None):
+                 telemetry: Optional[Counters] = None,
+                 layers_in_place: bool = False):
         self.single_step = single_step
         self.max_segment = int(max_segment)
         self.telemetry = Counters() if telemetry is None else telemetry
+        self._kernels: Dict[int, Set[str]] = {}   # chunk length -> notes
         vstep = jax.vmap(single_step, in_axes=(0, 0, 0, None))
+        pin = _stored_layout if layers_in_place else (lambda tree: tree)
 
         def run_segment(params_stack, opt_state, batch_seg, lrs):
             def body(carry, xs):
                 batch, lr = xs
-                p, o, losses = vstep(carry[0], carry[1], batch, lr)
-                return (p, o), losses
+                p, o, losses = vstep(*pin(carry), batch, lr)
+                return pin((p, o)), losses
 
             with kernel_notes() as notes:
                 (p, o), losses = jax.lax.scan(
                     body, (params_stack, opt_state), (batch_seg, lrs))
             if "attention" in notes:
                 self.telemetry.attention_kernel_layers += 1
+            self._kernels[lrs.shape[0]] = notes
             return p, o, losses          # losses: (n, M)
 
         can_donate = (jax.default_backend() != "cpu" if donate is None
@@ -224,6 +242,8 @@ class SegmentRunner:
                 params_stack, opt_state, losses = self._fn(
                     params_stack, opt_state, chunk, lrs[i:i + c])
                 self.telemetry.scan_dispatched()
+                if "expert_matmul" in self._kernels.get(c, ()):
+                    self.telemetry.expert_matmul_kernel_layers += 1
                 loss_chunks.append(losses)
                 i += c
             losses = (loss_chunks[0] if len(loss_chunks) == 1
@@ -287,9 +307,9 @@ class CrossRegionTrainer:
         single_step = make_single_step(mcfg, weight_decay=tcfg.weight_decay)
         self._train_step = jax.jit(jax.vmap(single_step,
                                             in_axes=(0, 0, 0, None)))
-        self.segment_runner = SegmentRunner(single_step,
-                                            max_segment=tcfg.max_segment,
-                                            telemetry=self.telemetry)
+        self.segment_runner = SegmentRunner(
+            single_step, max_segment=tcfg.max_segment,
+            telemetry=self.telemetry, layers_in_place=mcfg.layers_in_place)
 
         def eval_loss(params, batch):
             loss, metrics = api.loss_fn(mcfg, params, batch)

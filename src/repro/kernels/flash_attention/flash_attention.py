@@ -145,7 +145,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[:, q_start:q_start + bq] = lse.reshape(1, bq)
 
 
-def _fwd(q, k, v, *, causal, window, interpret):
+def _fwd(q, k, v, *, causal, window, scale, interpret):
     B, H, sq, hd = q.shape
     KV, sk = k.shape[1], k.shape[2]
     group = H // KV
@@ -155,7 +155,7 @@ def _fwd(q, k, v, *, causal, window, interpret):
                            lambda b, h: (b, h // group, 0, 0))
     lse_spec = pl.BlockSpec((None, None, 1, sq), lambda b, h: (b, h, 0, 0))
     kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, causal=causal,
-                               window=window, scale=1.0 / math.sqrt(hd))
+                               window=window, scale=scale)
     return pl.pallas_call(
         kernel,
         grid=(B, H),
@@ -231,7 +231,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, do, lse, d, *, causal, window, interpret):
+def _bwd(q, k, v, do, lse, d, *, causal, window, scale, interpret):
     B, H, sq, hd = q.shape
     KV, sk = k.shape[1], k.shape[2]
     group = H // KV
@@ -243,7 +243,7 @@ def _bwd(q, k, v, do, lse, d, *, causal, window, interpret):
     row_spec = pl.BlockSpec((None, None, 1, sq),
                             lambda b, kv, g: (b, kv * group + g, 0, 0))
     kernel = functools.partial(_bwd_kernel, b=b, causal=causal, window=window,
-                               scale=1.0 / math.sqrt(hd))
+                               scale=scale)
     return pl.pallas_call(
         kernel,
         grid=(B, KV, group),
@@ -270,31 +270,36 @@ def _bwd(q, k, v, do, lse, d, *, causal, window, interpret):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attention(q, k, v, causal, window, interpret):
-    return _fwd(q, k, v, causal=causal, window=window, interpret=interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _attention(q, k, v, causal, window, scale, interpret):
+    return _fwd(q, k, v, causal=causal, window=window, scale=scale,
+                interpret=interpret)[0]
 
 
-def _attention_fwd(q, k, v, causal, window, interpret):
-    o, lse = _fwd(q, k, v, causal=causal, window=window, interpret=interpret)
+def _attention_fwd(q, k, v, causal, window, scale, interpret):
+    o, lse = _fwd(q, k, v, causal=causal, window=window, scale=scale,
+                  interpret=interpret)
     return o, (q, k, v, o, lse)
 
 
-def _attention_bwd(causal, window, interpret, res, do):
+def _attention_bwd(causal, window, scale, interpret, res, do):
     q, k, v, o, lse = res
     d = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
                 axis=-1)[:, :, None, :]                         # (B, H, 1, S)
     return _bwd(q, k, v, do, lse, d, causal=causal, window=window,
-                interpret=interpret)
+                scale=scale, interpret=interpret)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
-def flash_attention_bhsd(q, k, v, *, causal=True, window=None,
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
+                                             "interpret"))
+def flash_attention_bhsd(q, k, v, *, causal=True, window=None, scale=None,
                          interpret=False):
     """q: (B, H, S, hd); k, v: (B, KV, S, hd), S a multiple of 128 (or at
-    most 128 and a multiple of 8). Returns (B, H, S, hd); differentiable in
-    q, k and v."""
-    return _attention(q, k, v, causal, window, interpret)
+    most 128 and a multiple of 8). The scores are q.k * scale (None:
+    1/sqrt(hd)). Returns (B, H, S, hd); differentiable in q, k and v."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    return _attention(q, k, v, causal, window, scale, interpret)

@@ -11,16 +11,19 @@ from repro.kernels.flash_attention.flash_attention import (flash_attention_bhsd,
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
 
-def flash_attention(q, k, v, *, causal=True, window=None, impl: str = "auto"):
+def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
+                    impl: str = "auto"):
     """q: (B, S, H, hd); k, v: (B, S, KV, hd) — the models' layout.
     Pads S to the kernel's block multiple and transposes to (B, H, S, hd)
-    for the kernel, which picks its block sizes from S.
+    for the kernel, which picks its block sizes from S. The scores are
+    q.k * scale (None: 1/sqrt(hd)).
     `impl`: "ref" = pure-jnp oracle; "auto"/"pallas" = Pallas kernel
     (interpret mode on CPU)."""
     if impl not in ("auto", "pallas", "ref"):
         raise ValueError(f"unknown impl {impl!r}; options: auto|pallas|ref")
     if impl == "ref":
-        return flash_attention_ref(q, k, v, causal=causal, window=window)
+        return flash_attention_ref(q, k, v, causal=causal, window=window,
+                                   scale=scale)
     S = q.shape[1]
     if k.shape[1] != S:
         raise ValueError(f"flash path needs as many keys as queries, got "
@@ -35,5 +38,5 @@ def flash_attention(q, k, v, *, causal=True, window=None, impl: str = "auto"):
                           ((0, 0), (0, 0), (0, pad), (0, 0)))
                   for x in (q, k, v))
     o = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                             interpret=is_cpu())
+                             scale=scale, interpret=is_cpu())
     return jnp.moveaxis(o[:, :, :S], 1, 2)

@@ -6,5 +6,5 @@ from __future__ import annotations
 from repro.models.layers import gqa_attention
 
 
-def flash_attention_ref(q, k, v, *, causal=True, window=None):
-    return gqa_attention(q, k, v, causal=causal, window=window)
+def flash_attention_ref(q, k, v, *, causal=True, window=None, scale=None):
+    return gqa_attention(q, k, v, causal=causal, window=window, scale=scale)

@@ -86,7 +86,7 @@ def _split_heads(x, n, hd):
 
 def gqa_attention(q, k, v, *, causal: bool, window: Optional[int],
                   q_positions=None, kv_positions=None, kv_mask=None,
-                  precision=None):
+                  precision=None, scale=None):
     """Reference GQA attention.
 
     q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd). H % KV == 0.
@@ -94,13 +94,15 @@ def gqa_attention(q, k, v, *, causal: bool, window: Optional[int],
     kv_mask: (B, Sk) bool validity mask (decode caches / padded encoders).
     precision: of both einsums (None = the backend default, which on TPU
     rounds the f32 softmax probabilities to bf16 for the PV product).
+    scale: of the scores q.k (None = 1/sqrt(hd)).
     Returns (B, Sq, H, hd).
     """
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     group = H // KV
     qh = q.reshape(B, Sq, KV, group, hd)
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", qh.astype(jnp.float32),
                         k.astype(jnp.float32),
                         precision=precision) * scale              # (B,KV,g,Sq,Sk)
@@ -178,8 +180,10 @@ def attn_out(o, lp, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def chunked_cross_entropy(h, lm_head, labels, *, chunk: int = 512):
-    """h: (B, S, D) final hidden states; lm_head: (D, V); labels: (B, S) int32.
+def chunked_cross_entropy(h, lm_head, labels, *, chunk: int = 512,
+                          scale: float = 1.0):
+    """h: (B, S, D) final hidden states; lm_head: (D, V); labels: (B, S) int32;
+    the logits are (h @ lm_head) * scale, h scaled in float32 first.
 
     Computes mean token NLL by scanning over sequence chunks so peak memory is
     O(B * chunk * V) instead of O(B * S * V) — the 256k-vocab archs need this.
@@ -190,7 +194,10 @@ def chunked_cross_entropy(h, lm_head, labels, *, chunk: int = 512):
     rem = S - n * chunk
 
     def chunk_nll(hc, lc):
-        logits = (hc.astype(jnp.float32) @ lm_head.astype(jnp.float32))
+        hc = hc.astype(jnp.float32)
+        if scale != 1.0:
+            hc = hc * scale
+        logits = (hc @ lm_head.astype(jnp.float32))
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
         return lse - gold                                          # (B, chunk)

@@ -94,8 +94,26 @@ def _seq_shard(x):
         x, NamedSharding(mesh, P(None, "model", None)))
 
 
+def _residual(cfg: ModelConfig, branch):
+    """A residual branch times `residual_multiplier` (no op at 1)."""
+    m = cfg.residual_multiplier
+    return branch if m == 1.0 else branch * m
+
+
+def _refuse_scalars(cfg: ModelConfig, what: str) -> None:
+    """The decode and prefill paths apply none of the muP scalars (nor does
+    `flash_decode` take a scale): refuse a model that has them rather than
+    decode a different one."""
+    if not cfg.scalars_are_identity:
+        raise NotImplementedError(
+            f"{what} does not apply {cfg.name}'s embedding, attention, "
+            f"residual or logit scalars; serving it is not supported")
+
+
 def _layer(cfg: ModelConfig, x, lp, positions, window, attn_impl,
            seq_parallel=False):
+    """One pre-norm block: (x, aux), where aux is the MoE layer's routing
+    load (2, E), or 0 for a dense MLP."""
     # named scopes `attention` and `mlp` reach every op's HLO metadata
     # (forward, remat recompute and backward), so a device trace can be
     # split by layer
@@ -104,19 +122,22 @@ def _layer(cfg: ModelConfig, x, lp, positions, window, attn_impl,
     with jax.named_scope("attention"):
         h = rms_norm(x, lp["ln1"], cfg.rms_eps)
         q, k, v = attn_qkv(h, lp["attn"], cfg, positions)
+        scale = cfg.attention_multiplier
         if attn_impl == "flash":
             from repro.kernels.flash_attention import ops as flash_ops
             note_kernel("attention")
-            o = flash_ops.flash_attention(q, k, v, causal=True, window=window)
+            o = flash_ops.flash_attention(q, k, v, causal=True, window=window,
+                                          scale=scale)
         else:
             o = gqa_attention(q, k, v, causal=True, window=window,
-                              q_positions=positions, kv_positions=positions)
-        x = x + attn_out(o, lp["attn"], cfg)
+                              q_positions=positions, kv_positions=positions,
+                              scale=scale)
+        x = x + _residual(cfg, attn_out(o, lp["attn"], cfg))
     with jax.named_scope("mlp"):
         h = rms_norm(x, lp["ln2"], cfg.rms_eps)
         if cfg.moe is not None:
             out = moe_lib.moe_ffn(h, lp["moe"], cfg.moe)
-            return x + out.y, out.aux_loss
+            return x + _residual(cfg, out.y), out.load
         return x + swiglu(h, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
                           lp["mlp"]["w_down"]), jnp.zeros((), jnp.float32)
 
@@ -127,6 +148,8 @@ def embed_inputs(cfg: ModelConfig, params, batch):
     with jax.named_scope("embed"):
         emb = params["embed"]
         x = emb[batch["tokens"]].astype(jnp.dtype(cfg.compute_dtype))
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         n_prefix = 0
         if cfg.family == "vlm" and "prefix_emb" in batch:
             pj = params["projector"]
@@ -147,25 +170,35 @@ def forward(cfg: ModelConfig, params, batch, *, train: bool = True,
     x, positions, n_prefix = embed_inputs(cfg, params, batch)
     window = cfg.attn_window
 
-    def body(carry, lp):
+    layers = params["layers"]
+    if cfg.layers_in_place:   # the scan runs over layer indices
+        xs = jnp.arange(cfg.n_layers)
+        layer = lambda l: jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, l, keepdims=False),
+            layers)
+    else:                     # the scan runs over the stack itself
+        xs, layer = layers, lambda lp: lp
+
+    def body(carry, xs_l):
         x = carry
-        y, aux = _layer(cfg, x, lp, positions, window, attn_impl,
+        y, aux = _layer(cfg, x, layer(xs_l), positions, window, attn_impl,
                         seq_parallel=seq_parallel)
         return y, aux
 
     if unroll:  # roofline probes: loop bodies visible to HLO cost analysis
         auxs = []
         for l in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[l], params["layers"])
-            x, aux = body(x, lp)
+            x, aux = body(x, jax.tree.map(lambda a: a[l], xs))
             auxs.append(aux)
         auxs = jnp.stack(auxs)
     else:
         body_fn = jax.checkpoint(body) if (train and remat) else body
-        x, auxs = jax.lax.scan(body_fn, x, params["layers"])
+        x, auxs = jax.lax.scan(body_fn, x, xs)
     with jax.named_scope("head"):
         h = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    return h, {"moe_aux": jnp.sum(auxs), "n_prefix": n_prefix}
+    aux = (moe_lib.balance_loss(auxs, cfg.moe) if cfg.moe is not None
+           else jnp.sum(auxs))
+    return h, {"moe_aux": aux, "n_prefix": n_prefix}
 
 
 def loss_fn(cfg: ModelConfig, params, batch, *, attn_impl: str = "ref",
@@ -178,7 +211,8 @@ def loss_fn(cfg: ModelConfig, params, batch, *, attn_impl: str = "ref",
         h = h[:, n_prefix:]
     with jax.named_scope("head"):           # with forward's final norm
         nll = chunked_cross_entropy(h, lm_head_weight(cfg, params),
-                                    batch["labels"], chunk=xent_chunk)
+                                    batch["labels"], chunk=xent_chunk,
+                                    scale=1.0 / cfg.logits_scaling)
     loss = nll + aux["moe_aux"]
     return loss, {"nll": nll, "moe_aux": aux["moe_aux"], "ppl": jnp.exp(nll)}
 
@@ -214,6 +248,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
                 unroll: bool = False):
     """One-token decode. tokens: (B,) int32. Window falls back to the arch's native
     window; pass cfg.long_decode_window for the long_500k variant."""
+    _refuse_scalars(cfg, "decode_step")
     window = window if window is not None else cfg.attn_window
     params = cast_params_for_compute(cfg, params)
     pos = cache["pos"]
@@ -281,6 +316,7 @@ def decode_step_slotted(cfg: ModelConfig, params, cache, tokens, *, active,
     (the traced shapes never change with batch composition) but neither write
     their cache row nor advance their position — their writes land on a
     deliberately out-of-bounds column and are dropped."""
+    _refuse_scalars(cfg, "decode_step_slotted")
     window = window if window is not None else cfg.attn_window
     params = cast_params_for_compute(cfg, params)
     pos = cache["pos"]                                  # (B,)
@@ -327,6 +363,7 @@ def prefill_chunk_slotted(cfg: ModelConfig, params, cache, tokens, slot, start,
     cache['pos'][slot] = start + n_valid, and returns (last_logits, cache)
     where last_logits (V,) are the logits at the chunk's last valid token —
     the first-token sampling point when the chunk completes the prompt."""
+    _refuse_scalars(cfg, "prefill_chunk_slotted")
     window = window if window is not None else cfg.attn_window
     params = cast_params_for_compute(cfg, params)
     C = cache["k"].shape[2]
@@ -382,6 +419,7 @@ def prefill_chunk_slotted(cfg: ModelConfig, params, cache, tokens, slot, start,
 def prefill(cfg: ModelConfig, params, batch, *, cache_len: Optional[int] = None):
     """Run the prompt through the stack, returning (last-token logits, cache).
     Requires cache_len >= prompt length (no ring wrap during prefill)."""
+    _refuse_scalars(cfg, "prefill")
     params = cast_params_for_compute(cfg, params)
     x, positions, n_prefix = embed_inputs(cfg, params, batch)
     B, S = x.shape[:2]

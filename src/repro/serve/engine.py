@@ -127,6 +127,11 @@ class ServeEngine:
                 f"ServeEngine supports transformer decode (dense/moe), got "
                 f"family {cfg.family!r}; use the legacy lock-step path in "
                 f"launch/serve.py for SSM/hybrid archs")
+        if not cfg.scalars_are_identity:
+            raise NotImplementedError(
+                f"ServeEngine's decode and prefill apply none of "
+                f"{cfg.name}'s embedding, attention, residual or logit "
+                f"scalars; serving it is not supported")
         if mode not in self.MODES:
             raise ValueError(f"unknown mode {mode!r}; options: {self.MODES}")
         attn_impl = api.resolve_attn_impl(attn_impl)

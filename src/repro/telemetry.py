@@ -13,8 +13,10 @@ device-to-host reads by reason, and `boundary_s`, the host's time from the
 return of one segment's last blocking read to the next segment's first scan
 dispatch, while the device has nothing queued. `snapshot()` and
 `difference()` give the counts of an interval. `attention_kernel_layers`
-is counted at trace time: each compiled segment program whose layer body
-took the Pallas attention kernel (`kernel_notes`, `note_kernel`).
+is counted at trace time: each compiled segment program whose layer body took
+the Pallas attention kernel (`kernel_notes`, `note_kernel`).
+`expert_matmul_kernel_layers` is counted at dispatch: each scan chunk sent to
+a segment program whose MoE layer took the grouped-matmul kernels.
 
 Both are always on. Neither reaches `history` or a checkpoint: timings there
 would break the bitwise kill-and-resume contract.
@@ -71,6 +73,7 @@ class Counters:
     deliveries: int = 0
     boundary_s: float = 0.0
     attention_kernel_layers: int = 0
+    expert_matmul_kernel_layers: int = 0
     host_syncs: Dict[str, int] = dataclasses.field(default_factory=dict)
     _last_read: Optional[float] = dataclasses.field(default=None, init=False,
                                                     repr=False)
@@ -101,6 +104,8 @@ class Counters:
                 "deliveries": self.deliveries,
                 "boundary_s": self.boundary_s,
                 "attention_kernel_layers": self.attention_kernel_layers,
+                "expert_matmul_kernel_layers":
+                    self.expert_matmul_kernel_layers,
                 "host_syncs": dict(self.host_syncs)}
 
     def line(self) -> str:
@@ -112,7 +117,9 @@ class Counters:
                 f"{sum(syncs.values())} host syncs ({by_reason}), "
                 f"{self.initiations} initiations, {self.deliveries} "
                 f"deliveries, boundary {self.boundary_s:.3f} s host time, "
-                f"attention_kernel_layers {self.attention_kernel_layers}")
+                f"attention_kernel_layers {self.attention_kernel_layers}, "
+                f"expert_matmul_kernel_layers "
+                f"{self.expert_matmul_kernel_layers}")
 
 
 def difference(after: Dict[str, Any], before: Dict[str, Any]

@@ -122,6 +122,37 @@ def test_flash_attention_vjp_matches_ref(B, S, H, KV, hd, window, dtype):
         np.testing.assert_allclose(got / scale, want / scale, rtol=0, atol=tol)
 
 
+@pytest.mark.parametrize("B,S,H,KV,hd,window", [
+    (1, 256, 6, 2, 64, None),      # Granite's GQA 24/8 grouping, cut
+    (1, 200, 4, 1, 32, 64),        # padding path and a window
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_scale_matches_ref(B, S, H, KV, hd, window, dtype):
+    """A score scale other than 1/sqrt(hd) (Granite's attention_multiplier
+    1/64) reaches the forward kernel, the backward kernel and the custom
+    VJP: output and dq, dk, dv against `gqa_attention` at the same
+    scale."""
+    from repro.models.layers import gqa_attention
+    scale = 0.015625
+    q = rand(1, (B, S, H, hd), dtype, scale=4.0)
+    k = rand(2, (B, S, KV, hd), dtype, scale=4.0)
+    v = rand(3, (B, S, KV, hd), dtype)
+    do = rand(4, (B, S, H, hd), dtype)
+    out, vjp = jax.vjp(lambda *a: flash_attention(
+        *a, causal=True, window=window, scale=scale), q, k, v)
+    want, vjp_ref = jax.vjp(lambda *a: gqa_attention(
+        *a, causal=True, window=window, scale=scale), q, k, v)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
+    for got, ref in [(out, want)] + list(zip(vjp(do), vjp_ref(do))):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        s = np.abs(ref).max()
+        np.testing.assert_allclose(got / s, ref / s, rtol=0, atol=tol)
+    # the default scale is not the one asked for: the test can tell them
+    default = flash_attention(q, k, v, causal=True, window=window)
+    assert float(jnp.max(jnp.abs(default.astype(jnp.float32)
+                                 - out.astype(jnp.float32)))) > 10 * tol
+
+
 # compute dtype -> (loss rtol, per-leaf gradient gap over its norm): at
 # bf16 the kernel rounds P and dS to bf16 at the MXU where the CPU
 # reference keeps them f32, a ~1% gradient difference
@@ -294,3 +325,70 @@ def test_flash_decode_empty_slots_ignored():
     vc2 = vc.at[:, 8:].set(-1e9)
     out2 = flash_decode(q, kc2, vc2, kv_pos, qpos, bc=32)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul
+# ---------------------------------------------------------------------------
+
+from repro.kernels.grouped_matmul.ops import grouped_matmul  # noqa: E402
+from repro.kernels.grouped_matmul.ref import grouped_matmul_ref  # noqa: E402
+
+# (rows, k, n, group sizes over all groups, offset, groups held): empty
+# groups inside and at both ends of the held range, rows past the groups'
+# sum, a held range that starts mid-tile, and k and n under a lane
+GMM_CASES = {
+    "shard": (300, 256, 384, (40, 0, 100, 0, 90, 30), 2, 3),
+    "whole": (256, 128, 128, (0, 64, 0, 70, 122), 0, 5),
+    "tail": (130, 64, 96, (7, 0, 50, 60), 1, 2),
+    "empty_share": (128, 128, 128, (60, 0, 0, 50), 1, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GMM_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_matmul_matches_ref(case, dtype):
+    """The kernels in interpret mode against the ragged_dot oracle: the
+    output, the gradient of lhs (`expert_gmm` against the transposed
+    weights) and of the weights (`expert_tgmm`); rows of groups not held,
+    and rows past the groups, are zero."""
+    m, k, n, sizes, off, g = GMM_CASES[case]
+    gs, off = jnp.array(sizes, jnp.int32), jnp.array(off, jnp.int32)
+    lhs, rhs = rand(1, (m, k), dtype), rand(2, (g, k, n), dtype, scale=0.1)
+    ct = rand(3, (m, n), dtype)
+
+    def run(impl):
+        f = lambda a, b: grouped_matmul(a, b, gs, off, impl=impl)  # noqa
+        out, vjp = jax.vjp(f, lhs, rhs)
+        return (out,) + vjp(ct)
+
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
+    for got, want in zip(run("pallas"), run("ref")):
+        assert got.dtype == want.dtype == dtype
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        s = max(np.abs(want).max(), 1e-6)
+        np.testing.assert_allclose(got / s, want / s, rtol=0, atol=tol)
+    out = np.asarray(run("pallas")[0], np.float32)
+    ends = np.cumsum(sizes)
+    lo, hi = ends[int(off)] - sizes[int(off)], ends[int(off) + g - 1]
+    assert not out[:lo].any() and not out[hi:].any()
+
+
+def test_grouped_matmul_folds_a_vmapped_batch():
+    """Under vmap the kernels take the batch as B x G groups in one call:
+    each batch element's output and gradients are its own call's."""
+    m, k, n, g = 200, 128, 128, 3
+    lhs, rhs = rand(1, (2, m, k)), rand(2, (2, g, k, n), scale=0.1)
+    gs = jnp.array([[50, 0, 70, 10], [0, 120, 30, 40]], jnp.int32)
+
+    def loss(impl):
+        def f(a, b, s):
+            return jnp.sum(jnp.sin(grouped_matmul(a, b, s, jnp.int32(1),
+                                                  impl=impl)))
+        return jax.vmap(jax.value_and_grad(f, (0, 1)))
+
+    (v, (da, db)), (vr, (dar, dbr)) = (loss(i)(lhs, rhs, gs)
+                                       for i in ("pallas", "ref"))
+    for got, want in ((v, vr), (da, dar), (db, dbr)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-4)

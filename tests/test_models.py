@@ -64,9 +64,18 @@ def test_smoke_train_step_no_nan(arch):
     assert moved > 0.0
 
 
+def _served(cfg):
+    """The decode paths apply none of the muP scalars and refuse a model
+    that has them (Granite): decode its layers with the scalars at the
+    identity."""
+    return dataclasses.replace(cfg, embedding_multiplier=1.0,
+                               attention_multiplier=None,
+                               residual_multiplier=1.0, logits_scaling=1.0)
+
+
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_smoke_decode_step(arch):
-    cfg = get_config(arch).reduced()
+    cfg = _served(get_config(arch).reduced())
     params = api.init_params(cfg, KEY)
     B = 2
     cache = api.init_cache(cfg, B, 16)
@@ -87,9 +96,8 @@ def _f32(cfg):
 def test_decode_matches_forward(arch):
     """Token-by-token decode reproduces the training forward's next-token logits
     (teacher forcing) — validates cache/ring-buffer/recurrent-state handling.
-    MoE uses a short prompt so capacity (>=8/expert) can never drop tokens in the
-    forward pass (decode batches are 1 token and never drop)."""
-    cfg = _f32(get_config(arch).reduced())
+    The MoE layer is dropless in both; MoE keeps its short prompt."""
+    cfg = _served(_f32(get_config(arch).reduced()))
     params = api.init_params(cfg, KEY)
     B, S = 1, 4 if cfg.moe is not None else 12
     toks = jax.random.randint(jax.random.fold_in(KEY, 9), (B, S), 0, cfg.vocab)
@@ -161,8 +169,12 @@ def test_moe_routes_to_multiple_experts():
     x = jax.random.normal(jax.random.fold_in(KEY, 3), (2, 16, D))
     out = moe_ffn(x, lp, mcfg)
     assert out.y.shape == x.shape
-    assert jnp.isfinite(out.aux_loss)
-    assert float(out.overflow_frac) < 0.5
+    share, prob = out.load
+    # every token's top_k choices are counted, over more than one expert
+    np.testing.assert_allclose(float(jnp.sum(share)), mcfg.top_k, rtol=1e-6)
+    assert int(jnp.sum(share > 0)) > 1
+    np.testing.assert_allclose(float(jnp.sum(prob)), 1.0, rtol=1e-5)
+    assert jnp.isfinite(moe_lib.balance_loss(out.load[None], mcfg))
 
 
 def test_moe_identical_tokens_identical_outputs():
@@ -177,7 +189,7 @@ def test_moe_identical_tokens_identical_outputs():
                       moe_lib.init_moe_params(KEY, 1, D, F, mcfg, jnp.float32))
     v = jax.random.normal(jax.random.fold_in(KEY, 4), (D,))
     x = jnp.broadcast_to(v, (1, 8, D))
-    out = moe_ffn(x, lp, mcfg, capacity_factor=8.0)  # big capacity: no drops
+    out = moe_ffn(x, lp, mcfg)                     # dropless: no capacity
     y = np.asarray(out.y[0])
     for t in range(1, 8):
         np.testing.assert_allclose(y[t], y[0], rtol=1e-4, atol=1e-5)
@@ -224,3 +236,23 @@ def test_decode_flash_impl_matches_ref():
         toks = jnp.argmax(l1, -1).astype(jnp.int32)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), rtol=1e-4,
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("path", ["decode_step", "prefill", "serve_engine"])
+def test_scaled_model_is_refused_by_the_serve_paths(path):
+    """Granite's muP scalars are applied in training only: the decode and
+    prefill paths, and ServeEngine, refuse such a model with a clear error
+    instead of decoding a different one."""
+    from repro.models import transformer
+    from repro.serve import ServeEngine
+    cfg = _f32(get_config("granite_moe_3b_a800m").reduced())
+    assert not cfg.scalars_are_identity and _served(cfg).scalars_are_identity
+    params = api.init_params(cfg, KEY)
+    toks = jnp.zeros((1, 4), jnp.int32)
+    with pytest.raises(NotImplementedError, match="scalars"):
+        if path == "decode_step":
+            api.decode_step(cfg, params, api.init_cache(cfg, 1, 8), toks[:, 0])
+        elif path == "prefill":
+            transformer.prefill(cfg, params, {"tokens": toks})
+        else:
+            ServeEngine(cfg, params, n_slots=2, cache_len=16)

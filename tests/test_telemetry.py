@@ -1,6 +1,7 @@
 """The trainer's own host spans, counters and named scopes
 (`repro.telemetry`): a tiny CoCoDC trainer traced by `jax.profiler` and
 read back with `ProfileData`, and the segment program's HLO metadata."""
+import dataclasses
 import glob
 import re
 import shutil
@@ -162,3 +163,30 @@ def test_read_blocks_and_counts_by_reason(reason):
     assert diff["host_syncs"] == {reason: 1}
     assert diff["scan_dispatches"] == 2
     assert diff["boundary_s"] > 0 and c._last_read is None
+
+
+def test_expert_kernel_counter_counts_dispatches(monkeypatch):
+    """`expert_matmul_kernel_layers` counts every chunk dispatched to a
+    program whose MoE layer took the grouped-matmul kernels (interpreted
+    here), so an interval's difference shows the kernels ran; on the
+    oracle it stays 0."""
+    from repro.kernels.grouped_matmul import ops as gmm_ops
+    cfg = dataclasses.replace(get_config("granite_moe_3b_a800m").reduced(),
+                              n_layers=1, d_model=64, d_ff=32, vocab=128,
+                              n_heads=2, n_kv_heads=1, head_dim=32)
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    stack = jax.tree.map(lambda a: jnp.stack([a, a]), params)
+    opt = jax.vmap(adamw_init)(stack)
+    batch = {k: jnp.zeros((3, 2, 1, 8), jnp.int32)
+             for k in ("tokens", "labels")}
+    counts = {}
+    for kernel in (False, True):
+        monkeypatch.setattr(gmm_ops, "uses_kernel", lambda impl="auto": kernel)
+        runner = SegmentRunner(make_single_step(cfg, weight_decay=0.1),
+                               layers_in_place=cfg.layers_in_place)
+        before = runner.telemetry.snapshot()
+        for _ in range(2):               # 3 steps: chunks of 2 and 1
+            runner(stack, opt, batch, jnp.zeros((3,)))
+        counts[kernel] = telemetry.difference(
+            runner.telemetry.snapshot(), before)["expert_matmul_kernel_layers"]
+    assert counts == {False: 0, True: 4}

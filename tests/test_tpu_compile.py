@@ -13,12 +13,16 @@ program that does not fit HBM. Covered here:
   * the trainer's segment program (the same `single_step` the trainer
     scans, with the flash attention kernel and its backward pass as on the
     chip) at paper_150m widths with M=4 regions vmapped on one chip, whose
-    peak, plus the resident cocodc engine state, must fit 16 GB of HBM.
+    peak, plus the resident cocodc engine state, must fit 16 GB of HBM;
+  * the same for the Granite 3.0 3B-A800M cut of the benchmark (4 layers,
+    10 of 40 experts held, vocab 12288), with the grouped-matmul kernels of
+    its MoE layer beside the flash kernels.
 
 Only one process at a time may load the TPU library, so the topology is
 described inside a module fixture, never at import.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -181,6 +185,49 @@ def _nbytes(tree):
 
 def test_paper_150m_segment_fits_v5e_hbm(cfg, param_shapes, sds,
                                         monkeypatch):
+    ma, engine, _ = _compile_segment(cfg, param_shapes, sds, monkeypatch,
+                                     ("flash_attention_bwd",))
+    program = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+               - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    total = program + engine
+    assert total < 0.9 * HBM_BYTES, (program, engine)
+
+
+def granite_cut():
+    """The benchmark's Granite 3.0 3B-A800M cut: one stage of 4 layers,
+    expert shard 0 of 4 (10 of 40 experts), a quarter of the vocabulary."""
+    full = get_config("granite_moe_3b_a800m")
+    return dataclasses.replace(
+        full, n_layers=4, vocab=12288,
+        moe=dataclasses.replace(full.moe, expert_shards=4, expert_shard=0))
+
+
+def test_granite_cut_segment_fits_v5e_hbm(sds, monkeypatch):
+    from repro.kernels.grouped_matmul import ops as gmm_ops
+    monkeypatch.setattr(gmm_ops, "is_cpu", lambda: False)
+    gcfg = granite_cut()
+    shapes = jax.eval_shape(lambda k: api.init_params(gcfg, k),
+                            jax.random.PRNGKey(0))
+    ma, engine, text = _compile_segment(
+        gcfg, shapes, sds, monkeypatch,
+        ("flash_attention_fwd", "flash_attention_bwd", "expert_gmm",
+         "expert_tgmm"))
+    # the layers are read in place: no weight or moment of the carry is
+    # copied into a layer-major layout for the segment
+    assert gcfg.layers_in_place
+    assert not _relaid_state(text), _relaid_state(text)
+    # argument plus temp bytes overstate this program's peak by more than
+    # the 10% margin above: the compiler's own peak, with the engine state
+    # resident beside it, has to fit the chip
+    assert ma.peak_memory_in_bytes + engine < HBM_BYTES, (
+        ma.peak_memory_in_bytes, engine)
+
+
+def _compile_segment(cfg, param_shapes, sds, monkeypatch, kernels):
+    """Compile the trainer's segment program for a described v5e, check it
+    holds `kernels` and updates its carry in place; returns its memory
+    analysis, the bytes of the cocodc engine state resident beside it
+    and its text."""
     from repro.kernels.flash_attention import ops as fa_ops
     # the chip's path: the flash kernel with its backward pass, lowered
     # (the host backend is the CPU, which would interpret it)
@@ -193,20 +240,32 @@ def test_paper_150m_segment_fits_v5e_hbm(cfg, param_shapes, sds,
              for k in ("tokens", "labels")}
     runner = SegmentRunner(make_single_step(cfg, weight_decay=0.1,
                                             attn_impl="flash"),
-                           donate=True)
+                           donate=True, layers_in_place=cfg.layers_in_place)
     compiled = runner._fn.fn.lower(stack, opt, batch,
                                    sds((1,), jnp.float32)).compile()
-    assert "flash_attention_bwd" in compiled.as_text()
+    text = compiled.as_text()
+    for kernel in kernels:
+        assert kernel in text, kernel
     ma = compiled.memory_analysis()
     # the carry is donated: params stack + AdamW moments update in place
     assert ma.alias_size_in_bytes >= _nbytes(stack) + _nbytes(opt)
-    program = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-               - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     # resident beside the segment: theta_g, momentum, the in-flight delta
     # and the worker-stacked Algorithm-1 snapshot
     ccfg = CoCoDCConfig(num_workers=M, num_fragments=K)
     frag = make_fragmenter(cfg, param_shapes, K)
     engine = jax.eval_shape(
         lambda s: es.init_state("cocodc", ccfg, s, frag), stack)
-    total = program + _nbytes(engine)
-    assert total < 0.9 * HBM_BYTES, (program, _nbytes(engine))
+    return ma, _nbytes(engine), text
+
+
+def _relaid_state(text):
+    """The leaves of the scan's carry (params stack, AdamW state) that a
+    compiled segment program copies into a layout other than row-major."""
+    out = []
+    for m in re.finditer(r"= \w+\[[\d,]*\]\{([\d,]*)[:}][^\n]*? "
+                         r"copy\(%((?:params_stack|opt_state)[\w.]*)\)",
+                         text):
+        minor_to_major = [int(d) for d in m.group(1).split(",") if d]
+        if minor_to_major != sorted(minor_to_major, reverse=True):
+            out.append(m.group(2))
+    return out
